@@ -18,6 +18,9 @@ import traceback
 def main() -> None:
     import os
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (
         agg_backends, beyond_paper, cifar_task, fault_tolerance, figures,
         kernels_bench, lm_throughput, moe_ablation, participation,

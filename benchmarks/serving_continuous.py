@@ -15,10 +15,11 @@ trace against three arms:
   constant-shape scatter, and decode runs device-side in K-step
   ``lax.while_loop`` chunks (one ``done``-vector sync per chunk);
 * ``continuous+mesh`` — the same engine with the stacked ``(D, ...)``
-  replica axis sharded across a cluster mesh; runs in a subprocess with
-  ``XLA_FLAGS=--xla_force_host_platform_device_count`` (device count locks
-  at first jax init), and its outputs are compared bitwise against the
-  in-process continuous arm.
+  replica axis sharded one cluster per device, in this process, and its
+  outputs compared bitwise against the continuous arm.  It runs only when
+  the host has at least ``D`` devices (on CPU, start the process with
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=4``); otherwise it is
+  reported as not run.
 
 In-bench gates (all hard asserts, mirrored by the CI schema check):
 
@@ -39,8 +40,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -65,7 +64,6 @@ BUCKETS = (16, 32)
 MAX_BATCH = 8
 GEN_CAP = 32
 CHUNK_STEPS = 8
-MESH_MARKER = "MESH_ARM_RESULT "
 # fp32 so the continuous==static and mesh==continuous checks are exact
 TINY_ARCH = dict(num_layers=2, d_model=32, d_ff=64, num_heads=2,
                  num_kv_heads=1, head_dim=16, dtype="float32", remat=False)
@@ -77,7 +75,7 @@ def _fresh(trace):
 
 
 def _setup(train_steps: int, n_requests: int):
-    """Deterministic scenario + trace (the mesh subprocess rebuilds both)."""
+    """Deterministic scenario + trace."""
     run = build_scenario(SCENARIO, arch_overrides=TINY_ARCH)
     run.run(train_steps)
     trace = synthetic_trace(
@@ -106,75 +104,24 @@ def _replay(server, trace, warmup):
     }
 
 
-def _save_stack(stack, path: str) -> None:
-    """Flattened-leaf npz snapshot (canonical jax tree order)."""
-    leaves = jax.tree_util.tree_leaves(stack)
-    np.savez(path, **{f"leaf{i}": np.asarray(x) for i, x in enumerate(leaves)})
-
-
-def _load_stack(treedef_like, path: str):
-    """Rebuild a stack from npz onto ``treedef_like``'s tree structure."""
-    data = np.load(path)
-    treedef = jax.tree_util.tree_structure(treedef_like)
-    return jax.tree_util.tree_unflatten(
-        treedef, [data[f"leaf{i}"] for i in range(treedef.num_leaves)]
-    )
-
-
-def _mesh_arm(train_steps: int, n_requests: int, num_clusters: int,
-              stack_path: str) -> dict:
-    """Run the continuous+mesh arm in a subprocess with forced host devices.
-
-    The subprocess loads the parent's trained stack from ``stack_path``
-    rather than retraining: the arm measures *serving* under mesh sharding,
-    and multi-device XLA compiles training differently enough to drift off
-    the parent's weights bitwise.
-    """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (
-        f"--xla_force_host_platform_device_count={num_clusters} "
-        + env.get("XLA_FLAGS", "")
-    ).strip()
-    env.setdefault("PYTHONPATH", "src")
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.serving_continuous", "--mesh-arm",
-         "--train-steps", str(train_steps), "--requests", str(n_requests),
-         "--stack-path", stack_path],
-        capture_output=True, text=True, env=env, timeout=1800,
-    )
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"mesh arm subprocess failed:\n{proc.stdout[-2000:]}\n"
-            f"{proc.stderr[-2000:]}"
-        )
-    for line in reversed(proc.stdout.splitlines()):
-        if line.startswith(MESH_MARKER):
-            return json.loads(line[len(MESH_MARKER):])
-    raise RuntimeError(f"mesh arm produced no result line:\n{proc.stdout[-2000:]}")
-
-
-def mesh_arm_main(train_steps: int, n_requests: int, stack_path: str) -> None:
-    """Subprocess entry: continuous serving with mesh-sharded replicas."""
+def _mesh_arm(model, stack, trace, num_clusters: int):
+    """Continuous serving with the replica stack sharded one cluster per
+    device, in this process.  Returns ``(row, served, mesh)``, or ``None``
+    when the host has fewer than ``num_clusters`` devices (on CPU, set
+    ``XLA_FLAGS=--xla_force_host_platform_device_count`` before starting)."""
     from repro.launch.mesh import make_cluster_mesh
 
-    # one binding step gives the stack's tree structure; the parent's
-    # trained leaves then replace the barely-trained ones
-    run, trace = _setup(1, n_requests)
-    stack = _load_stack(run.runtime.cluster_params(), stack_path)
-    mesh = make_cluster_mesh(run.scenario.num_clusters)
+    if len(jax.devices()) < num_clusters:
+        return None
+    mesh = make_cluster_mesh(num_clusters)
     server = ContinuousFederatedServer(
-        run.runtime.model, stack, mesh=mesh,
+        model, stack, mesh=mesh,
         max_batch=MAX_BATCH, length_buckets=BUCKETS, gen_cap=GEN_CAP,
         chunk_steps=CHUNK_STEPS,
     )
     served = _fresh(trace)
     _, row = _replay(server, served, trace)
-    print(MESH_MARKER + json.dumps({
-        **row,
-        "devices": len(jax.devices()),
-        "mesh_axes": dict(zip(server.mesh.axis_names, server.mesh.devices.shape)),
-        "outputs": [r.output.tolist() for r in served],
-    }))
+    return row, served, mesh
 
 
 def main(smoke: bool = False) -> dict:
@@ -225,20 +172,19 @@ def main(smoke: bool = False) -> dict:
         f"per-bucket programs recompiled: {counts} vs {len(used_buckets)} buckets"
     )
 
-    stack_path = os.path.join(RESULTS, "_serving_continuous_stack.npz")
-    _save_stack(stack, stack_path)
-    try:
-        mesh_row = _mesh_arm(train_steps, n_requests, sc.num_clusters, stack_path)
-    finally:
-        os.unlink(stack_path)
-    mesh_outputs = [np.asarray(o, np.int32) for o in mesh_row.pop("outputs")]
-    mesh_bitwise = all(
-        np.array_equal(a, b.output) for a, b in zip(mesh_outputs, cont_done)
-    )
-    assert mesh_bitwise, "mesh-sharded replicas diverged from the vmap fallback"
-    devices = mesh_row.pop("devices")
-    mesh_axes = mesh_row.pop("mesh_axes")
-    rows.append({"arm": "continuous+mesh", **mesh_row})
+    mesh_arm = _mesh_arm(model, stack, trace, sc.num_clusters)
+    if mesh_arm is None:
+        print(f"  continuous+mesh: not run ({len(jax.devices())} devices < "
+              f"{sc.num_clusters} clusters)")
+        mesh_bitwise, mesh_axes = None, None
+    else:
+        mesh_row, mesh_done, mesh = mesh_arm
+        mesh_bitwise = all(
+            np.array_equal(a.output, b.output) for a, b in zip(mesh_done, cont_done)
+        )
+        assert mesh_bitwise, "mesh-sharded replicas diverged from the vmap fallback"
+        mesh_axes = dict(zip(mesh.axis_names, mesh.devices.shape))
+        rows.append({"arm": "continuous+mesh", **mesh_row})
 
     for r in rows:
         print(f"  {r['arm']:16s} {r['qps']:8.2f} req/s {r['tokens_per_sec']:9.1f} "
@@ -257,7 +203,7 @@ def main(smoke: bool = False) -> dict:
             "max_batch": MAX_BATCH, "gen_cap": GEN_CAP,
             "chunk_steps": CHUNK_STEPS, "buckets": list(BUCKETS),
             "budget_law": "heavy-tail [1, gen_cap] exp 1.1",
-            "mesh_devices": devices, "mesh_axes": mesh_axes,
+            "mesh_devices": len(jax.devices()), "mesh_axes": mesh_axes,
             "smoke": smoke, "jax_backend": jax.default_backend(),
             "arch": "2L d_model=32 d_ff=64 fp32",
         },
@@ -265,7 +211,7 @@ def main(smoke: bool = False) -> dict:
         "headline": {
             "qps_static": qps_static,
             "qps_continuous": qps_cont,
-            "qps_mesh": rows[2]["qps"],
+            "qps_mesh": rows[2]["qps"] if mesh_arm is not None else None,
             "qps_ratio": ratio,
             "bitwise_continuous_vs_static": bitwise,
             "bitwise_mesh_vs_continuous": mesh_bitwise,
@@ -296,14 +242,4 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny trace for the CI regression gate")
-    ap.add_argument("--mesh-arm", action="store_true",
-                    help="internal: run the mesh-sharded arm (subprocess)")
-    ap.add_argument("--train-steps", type=int, default=24)
-    ap.add_argument("--requests", type=int, default=96)
-    ap.add_argument("--stack-path", default=None,
-                    help="internal: npz of the parent's trained stack")
-    args = ap.parse_args()
-    if args.mesh_arm:
-        mesh_arm_main(args.train_steps, args.requests, args.stack_path)
-    else:
-        main(smoke=args.smoke)
+    main(smoke=ap.parse_args().smoke)

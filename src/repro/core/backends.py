@@ -420,8 +420,6 @@ class CollectiveBackend:
 
     def _shard_map_transition(self, stacked: PyTree, event: AggregationEvent,
                               m_hat: jax.Array) -> PyTree:
-        from repro.sharding.compat import shard_map_compat
-
         specs = self.param_specs
         if specs is None:
             # default layout: every stacked leaf is sharded on its leading
@@ -446,9 +444,9 @@ class CollectiveBackend:
 
             return jax.tree.map(per_leaf, tree)
 
-        return shard_map_compat(
+        return jax.shard_map(
             agg, mesh=self.mesh,
-            in_specs=(specs, w_spec), out_specs=specs,
+            in_specs=(specs, w_spec), out_specs=specs, check_vma=False,
         )(stacked, m_hat)
 
     def _shard_map_transition_p(self, stacked: PyTree, event: AggregationEvent,
@@ -460,8 +458,6 @@ class CollectiveBackend:
         stays bitwise-identical to pre-fault code, while this one threads the
         per-round (D,) vectors through as replicated shard_map operands.
         """
-        from repro.sharding.compat import shard_map_compat
-
         specs = self.param_specs
         if specs is None:
             specs = jax.tree.map(
@@ -484,9 +480,10 @@ class CollectiveBackend:
             return jax.tree.map(per_leaf, tree)
 
         wl, ws, wr = ring_w
-        return shard_map_compat(
+        return jax.shard_map(
             agg, mesh=self.mesh,
             in_specs=(specs, w_spec, rep, rep, rep), out_specs=specs,
+            check_vma=False,
         )(stacked, m_hat, wl, ws, wr)
 
     # -- factors -------------------------------------------------------------

@@ -20,8 +20,7 @@ is benchmarked (benchmarks/lm_throughput.py) and bitwise-tested
 Fused-kernel path: when the optimizer is plain SGD with a static learning
 rate and the selected aggregation backend is Pallas, the parameter update
 runs through ``kernels.fused_sgd`` (one fused multiply-subtract per tile,
-f32 accumulation).  Leaves whose flat size does not tile fall back to the
-dense expression of the *same* f32 math, so the fused path is
+f32 accumulation), every leaf in its own layout, so the fused path is
 dense-equivalent leaf by leaf.
 """
 from __future__ import annotations
@@ -55,25 +54,6 @@ def fused_sgd_applicable(opt, backend) -> bool:
     )
 
 
-def _fused_sgd_apply(params: PyTree, grads: PyTree, lr: float, *,
-                     interpret: bool, tile_m: int) -> PyTree:
-    from ..kernels import sgd_update
-
-    def per_leaf(w, g):
-        flat = w.reshape(-1)
-        gflat = g.reshape(-1)
-        if flat.size % tile_m:
-            # dense-equivalence fallback: the kernel's exact f32 math,
-            # expressed in plain XLA for leaves that don't tile
-            out = (flat.astype(jnp.float32) - lr * gflat.astype(jnp.float32))
-            return out.astype(w.dtype).reshape(w.shape)
-        return sgd_update(
-            flat, gflat, lr, interpret=interpret, tile_m=tile_m
-        ).reshape(w.shape)
-
-    return jax.tree.map(per_leaf, params, grads)
-
-
 def build_local_update(model, opt, *, backend=None, tile_m: int = 1024):
     """Returns ``local_update(params, opt_state, batch) -> (params,
     opt_state, losses)`` over stacked ``(C, ...)`` client trees.
@@ -81,10 +61,12 @@ def build_local_update(model, opt, *, backend=None, tile_m: int = 1024):
     ``batch`` leaves are ``(C, b, ...)``; ``losses`` is ``(C,)`` per-client
     loss.  One call is one fleet-wide SGD micro-step compiled as a single
     program (vmapped value_and_grad + vmapped optimizer update, or the
-    fused-SGD kernel when ``fused_sgd_applicable``).
+    fused-SGD kernel when ``fused_sgd_applicable``, run in the Pallas
+    backend's own interpret mode).
     """
+    from ..kernels import sgd_update_tree
+
     use_fused = fused_sgd_applicable(opt, backend)
-    interpret = bool(getattr(backend, "interpret", True))
 
     def client_grads(p, b):
         return jax.value_and_grad(model.loss)(p, b)
@@ -92,8 +74,8 @@ def build_local_update(model, opt, *, backend=None, tile_m: int = 1024):
     def local_update(params, opt_state, batch):
         losses, grads = jax.vmap(client_grads)(params, batch)
         if use_fused:
-            params = _fused_sgd_apply(
-                params, grads, opt.lr, interpret=interpret, tile_m=tile_m
+            params = sgd_update_tree(
+                params, grads, opt.lr, interpret=backend.interpret, tile_m=tile_m
             )
         else:
             params, opt_state = jax.vmap(opt.update)(params, grads, opt_state)

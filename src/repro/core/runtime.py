@@ -174,6 +174,21 @@ def stacked_init(model, num_copies: int, seed_or_key) -> PyTree:
     )
 
 
+@jax.jit
+def _contract_clients(params: PyTree, coef) -> PyTree:
+    """``sum_c coef[c, ...] * w[c]`` per leaf, in f32, kept in the leaf dtype.
+
+    ``coef`` is ``(C,)`` (consensus) or ``(C, D)`` (cluster stack).  The cast
+    back matters at real widths: a bf16 stack contracted to f32 would double
+    the serving replicas' device memory.
+    """
+    coef = jnp.asarray(coef, jnp.float32)
+    spec = "c...,c->..." if coef.ndim == 1 else "c...,cd->d..."
+    return jax.tree.map(
+        lambda w: jnp.einsum(spec, w, coef).astype(w.dtype), params
+    )
+
+
 def _event_time(
     latency: Optional[LatencyModel], alpha: int, event: str, profile=None,
     participants=None, clusters=None, t=None,
@@ -371,12 +386,15 @@ class SyncScheduler:
         spec = self._backend_spec
         if spec is None:
             spec = _legacy_impl_backend(cfg.aggregation_impl, agg_clusters, cfg.P())
-        from ..launch.mesh import resolve_client_mesh
+        from ..launch.mesh import resolve_client_mesh, shard_clients
 
         self.mesh = resolve_client_mesh(self._mesh_spec, agg_clusters.num_clients)
         self.backend = resolve_backend(
             spec, agg_clusters, cfg.P(), cfg.alpha, mesh=self.mesh
         )
+        if getattr(self.backend, "mesh", None) is not None and self.store.resident:
+            self.params = shard_clients(self.params, self.mesh,
+                                        self.backend.axis_name)
         if self.faults is not None and self.backend.name == "collective":
             # traced values can't be checked on device — validate the whole
             # fault horizon host-side once (raises naming the bad round)
@@ -428,19 +446,6 @@ class SyncScheduler:
                            donate_argnums=0)
 
         self._step_fns = {e: make_step(e) for e in ("local", "intra", "inter")}
-
-        def global_model(params):
-            return jax.tree.map(lambda w: jnp.einsum("c...,c->...", w, self._m), params)
-
-        self._global_model = jax.jit(global_model)
-        self._v = jnp.asarray(cfg.clusters.V(), jnp.float32)
-
-        def cluster_model(params):
-            return jax.tree.map(
-                lambda w: jnp.einsum("c...,cd->d...", w, self._v), params
-            )
-
-        self._cluster_model = jax.jit(cluster_model)
 
     # -- participation plumbing ----------------------------------------------
     def _round_of(self, k: int) -> int:
@@ -628,7 +633,7 @@ class SyncScheduler:
     def global_params(self) -> PyTree:
         """Consensus-phase output: sum_d m~_d y_K^(d) == sum_i m_i w_K^(i)."""
         if self.store.resident:
-            return self._global_model(self.params)
+            return _contract_clients(self.params, self._m)
         if self._buffer is None:
             return self.store.global_params()
         # mid-round: residents' live buffer + the store's cold majority
@@ -646,7 +651,7 @@ class SyncScheduler:
                 "cluster_params requires a resident client-state store; "
                 "serve host-offload runs from checkpoints instead"
             )
-        return self._cluster_model(self.params)
+        return _contract_clients(self.params, self.cfg.clusters.V())
 
 
 # ---------------------------------------------------------------------------
@@ -784,12 +789,15 @@ class RoundScheduler:
             # the compiled round engine historically always used dense;
             # honor impl="gossip" only where the collective path is valid
             spec = _legacy_impl_backend(fl.impl, agg_clusters, self._proto.P())
-        from ..launch.mesh import resolve_client_mesh
+        from ..launch.mesh import resolve_client_mesh, shard_clients
 
         self.mesh = resolve_client_mesh(self._mesh_spec, agg_clusters.num_clients)
         self.backend = resolve_backend(
             spec, agg_clusters, self._proto.P(), fl.alpha, mesh=self.mesh
         )
+        if getattr(self.backend, "mesh", None) is not None and self.store.resident:
+            self.params, self.opt_state = shard_clients(
+                (self.params, self.opt_state), self.mesh, self.backend.axis_name)
         if self.faults is not None and self.backend.name == "collective":
             # traced values can't be checked on device — validate the whole
             # fault horizon host-side once (raises naming the bad round)
@@ -1032,8 +1040,7 @@ class RoundScheduler:
         if not self.store.resident:
             # supersteps scatter before returning, so the store is the truth
             return self.store.global_params()
-        m = jnp.asarray(self._proto.clusters.m(), jnp.float32)
-        return jax.tree.map(lambda w: jnp.einsum("c...,c->...", w, m), self.params)
+        return _contract_clients(self.params, self._proto.clusters.m())
 
     def cluster_params(self) -> PyTree:
         """Stacked ``(D, ...)`` per-cluster models at the last round boundary.
@@ -1047,10 +1054,7 @@ class RoundScheduler:
                 "cluster_params requires a resident client-state store; "
                 "serve host-offload runs from checkpoints instead"
             )
-        v = jnp.asarray(self._proto.clusters.V(), jnp.float32)
-        return jax.tree.map(
-            lambda w: jnp.einsum("c...,cd->d...", w, v), self.params
-        )
+        return _contract_clients(self.params, self._proto.clusters.V())
 
 
 # ---------------------------------------------------------------------------

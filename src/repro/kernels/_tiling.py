@@ -1,49 +1,89 @@
-"""Shared flatten/pad/tile plumbing for the (rows, M) streaming kernels.
+"""Layout-preserving leaf views and VMEM block shapes for the streaming kernels.
 
-``cluster_agg_tree``, ``gossip_mix_tree`` and ``fused_transition_tree`` all
-apply a Pallas kernel that expects a 2-D ``(rows, M)`` operand with ``M``
-divisible by the lane tile.  This helper owns the leaf bookkeeping they used
-to copy-paste: flatten each pytree leaf to ``(rows, M)``, pad ``M`` up to a
-multiple of ``tile_m``, run the kernel, strip the padding and restore the
-leaf shape.  When ``M % tile_m == 0`` both the pad and the unpad slice are
-skipped entirely — aligned leaves stream through untouched.
+``fused_transition``, ``gossip_mix`` and ``cluster_agg`` mix the leading
+(client/cluster) rows of a stacked ``(rows, ...)`` parameter leaf;
+``fused_sgd`` updates a leaf elementwise.  A TPU stores such a leaf in its
+native tiled layout: the minor two dims in (sublane, 128-lane) tiles.
+Flattening it to ``(rows, M)`` makes XLA relayout the whole leaf, padding
+the few ``rows`` out to the sublane tile; at granite-8b widths that relayout
+stalled the round step's compile for minutes.  So the kernels never
+flatten.  A leaf ``(rows, *mid, n, l)`` is viewed as ``(rows, prod(mid) * n,
+l)``, a bitcast, and streamed in ``(rows, tn, tl)`` blocks over a ``cdiv``
+grid.  The edge blocks may be partial: every map here is pointwise over the
+``(n, l)`` positions, so the padding of an edge block never reaches a real
+output position.
 """
 from __future__ import annotations
 
-from typing import Any, Callable
-
 import jax
-import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["_tiled_tree_apply"]
+__all__ = ["block_shape", "mix", "stream_rows"]
 
-PyTree = Any
+# VMEM a block may use: double-buffered operand blocks plus the kernel's f32
+# working rows.  Half the 16 MiB scoped-VMEM default leaves the compiler room.
+_VMEM_BUDGET = 8 << 20
+
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _tiled_tree_apply(
-    fn: Callable[[jax.Array], jax.Array],
-    tree: PyTree,
-    rows: int,
-    out_rows: int | None = None,
-    tile_m: int = 512,
-) -> PyTree:
-    """Apply ``fn: (rows, M_padded) -> (out_rows, M_padded)`` to every leaf.
+def block_shape(n: int, l: int, *, lane_tile: int, bytes_per_pos: int,
+                itemsize: int) -> tuple[int, int]:
+    """``(tn, tl)`` for an ``(n, l)`` plane.
 
-    ``rows`` is the leading (client/cluster) axis of each leaf; ``out_rows``
-    defaults to ``rows`` (shape-preserving kernels like gossip mixing) and
-    differs for reductions (``cluster_agg``: C clients -> D clusters).
+    ``tl`` is ``l`` when it fits in ``lane_tile`` (a full dim is always a
+    legal block) and ``lane_tile`` otherwise, which on TPU is a multiple of
+    128.  ``tn`` fills the VMEM budget, given ``bytes_per_pos`` of VMEM per
+    ``(n, l)`` position, in multiples of the dtype's sublane tile.
     """
-    out_rows = rows if out_rows is None else out_rows
+    tl = l if l <= lane_tile else lane_tile
+    sublane = 8 * max(1, 4 // itemsize)
+    tn_max = _VMEM_BUDGET // (bytes_per_pos * tl)
+    if n <= tn_max:
+        return n, tl
+    return max(sublane, tn_max // sublane * sublane), tl
 
-    def per_leaf(w):
-        m = int(w.size // rows)
-        flat = w.reshape(rows, m)
-        pad = (-m) % tile_m
-        if pad:
-            flat = jnp.pad(flat, ((0, 0), (0, pad)))
-        out = fn(flat)
-        if pad:
-            out = out[:, :m]
-        return out.reshape((out_rows,) + w.shape[1:])
 
-    return jax.tree.map(per_leaf, tree)
+def mix(coef_ref, xs, out_idx, transpose=False):
+    """``sum_j coef[i, j] * xs[j]`` for one output row ``i`` (``coef[j, i]``
+    with ``transpose``)."""
+    acc = None
+    for j, x in enumerate(xs):
+        c = coef_ref[j, out_idx] if transpose else coef_ref[out_idx, j]
+        acc = c * x if acc is None else acc + c * x
+    return acc
+
+
+def stream_rows(kernel, w: jax.Array, factors: tuple, out_rows: int, *,
+                work_rows: int, lane_tile: int, interpret: bool) -> jax.Array:
+    """Run ``kernel(*factor_refs, w_ref, out_ref)`` over a stacked leaf.
+
+    ``w`` is ``(rows, ...)``; the result is ``(out_rows, ...)`` in ``w``'s
+    dtype.  ``factors`` are the small f32 mixing matrices, passed whole in
+    SMEM and read as scalars.  ``work_rows`` counts the f32 rows the kernel
+    keeps live per position (it sizes the blocks).  A shape-preserving map
+    writes in place: the output aliases ``w``, so under donation the step
+    needs no second copy of the leaf.
+    """
+    rows = w.shape[0]
+    # (rows, *mid, l) -> (rows, n, l): a bitcast, the lane dim stays last
+    x = w.reshape(rows, -1, w.shape[-1] if w.ndim > 1 else 1)
+    _, n, l = x.shape
+    isz = x.dtype.itemsize
+    tn, tl = block_shape(
+        n, l, lane_tile=lane_tile, itemsize=isz,
+        bytes_per_pos=2 * (rows + out_rows) * isz + 4 * work_rows,
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n, tn), pl.cdiv(l, tl)),
+        in_specs=[_SMEM] * len(factors) + [
+            pl.BlockSpec((rows, tn, tl), lambda i, j: (0, i, j)),
+        ],
+        out_specs=pl.BlockSpec((out_rows, tn, tl), lambda i, j: (0, i, j)),
+        out_shape=jax.ShapeDtypeStruct((out_rows, n, l), x.dtype),
+        input_output_aliases={len(factors): 0} if out_rows == rows else {},
+        interpret=interpret,
+    )(*factors, x)
+    return out.reshape((out_rows,) + w.shape[1:])

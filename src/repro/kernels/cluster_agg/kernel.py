@@ -1,35 +1,37 @@
 """Pallas TPU kernel: intra-cluster weighted aggregation (eq. 2).
 
-Stacked client models ``W`` (C, M) are reduced to cluster models ``Y`` (D, M)
-with per-client weights ``m^_i`` inside contiguous, uniform clusters of size
-``g = C / D``:
+Stacked client models ``W`` (C, ...) are reduced to cluster models
+``Y`` (D, ...) with per-client weights ``m^_i`` inside contiguous, uniform
+clusters of size ``g = C / D``:
 
     Y[d] = sum_{i in cluster d} m^_i * W[i]
 
-Bandwidth-bound streaming reduction: each grid step loads one cluster's
-(g, TM) tile plus its (1, g) weight row into VMEM and emits a (1, TM) tile.
+Bandwidth-bound streaming reduction: each grid step loads one VMEM block
+of every client's leaf and emits the matching block of every cluster's;
+the (D, g) weights sit in SMEM and are read as scalars.
 
-Block layout:
-    w tile:   (g, TM) VMEM, index (d, m)
-    weights:  (1, g)  VMEM, row d of the (D, g) weight matrix
-    out tile: (1, TM) VMEM
-Grid: (D, M // TM).
+Block layout (``_tiling.stream_rows``): each leaf is viewed as (C, n, l)
+without moving data and tiled as (C, tn, tl) -> (D, tn, tl) over a
+(cdiv(n, tn), cdiv(l, tl)) grid.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from .._tiling import stream_rows
 
 __all__ = ["cluster_agg_kernel", "cluster_agg_pallas"]
 
 
-def cluster_agg_kernel(w_ref, wt_ref, out_ref):
-    w = w_ref[...].astype(jnp.float32)        # (g, TM)
-    wt = wt_ref[...].astype(jnp.float32)      # (1, g)
-    out_ref[...] = jax.lax.dot_general(
-        wt, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(out_ref.dtype)                    # (1, TM)
+def cluster_agg_kernel(wt_ref, w_ref, out_ref):
+    d, g = wt_ref.shape
+    for k in range(d):
+        acc = None
+        for j in range(g):
+            t = wt_ref[k, j] * w_ref[k * g + j].astype(jnp.float32)
+            acc = t if acc is None else acc + t
+        out_ref[k] = acc.astype(out_ref.dtype)
 
 
 def cluster_agg_pallas(
@@ -39,23 +41,13 @@ def cluster_agg_pallas(
     tile_m: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """w: (C, M); weights: (C,) m^ ratios; clusters are contiguous C/D groups."""
-    c, m = w.shape
+    """w: (C, ...); weights: (C,) m^ ratios; clusters are contiguous C/D groups."""
+    c = w.shape[0]
     d = num_clusters
     if c % d:
         raise ValueError("C must be divisible by num_clusters")
-    g = c // d
-    if m % tile_m:
-        raise ValueError(f"M={m} must be divisible by tile_m={tile_m}")
-    wt = weights.reshape(d, g)
-    return pl.pallas_call(
-        cluster_agg_kernel,
-        grid=(d, m // tile_m),
-        in_specs=[
-            pl.BlockSpec((g, tile_m), lambda di, mi: (di, mi)),
-            pl.BlockSpec((1, g), lambda di, mi: (di, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tile_m), lambda di, mi: (di, mi)),
-        out_shape=jax.ShapeDtypeStruct((d, m), w.dtype),
-        interpret=interpret,
-    )(w, wt)
+    wt = weights.astype(jnp.float32).reshape(d, c // d)
+    return stream_rows(
+        cluster_agg_kernel, w, (wt,), d,
+        work_rows=2, lane_tile=tile_m, interpret=interpret,
+    )

@@ -5,7 +5,6 @@ import functools
 
 import jax
 
-from .._tiling import _tiled_tree_apply
 from .kernel import cluster_agg_pallas
 from .ref import cluster_agg_ref
 
@@ -15,17 +14,17 @@ __all__ = ["cluster_agg", "cluster_agg_tree"]
 @functools.partial(jax.jit, static_argnames=("num_clusters", "impl", "interpret", "tile_m"))
 def cluster_agg(w, weights, num_clusters: int, impl: str = "pallas",
                 interpret: bool = False, tile_m: int = 512):
+    """Aggregate one stacked (C, ...) leaf (``impl="ref"``: 2-D (C, M) only)."""
     if impl == "ref":
         return cluster_agg_ref(w, weights, num_clusters)
     return cluster_agg_pallas(w, weights, num_clusters, tile_m=tile_m, interpret=interpret)
 
 
-def cluster_agg_tree(tree, weights, num_clusters: int, impl: str = "pallas",
+def cluster_agg_tree(tree, weights, num_clusters: int,
                      interpret: bool = False, tile_m: int = 512):
     """Aggregate a (C, ...) stacked pytree into a (D, ...) pytree."""
-    c = weights.shape[0]
-    return _tiled_tree_apply(
-        lambda flat: cluster_agg(flat, weights, num_clusters, impl=impl,
-                                 interpret=interpret, tile_m=tile_m),
-        tree, rows=c, out_rows=num_clusters, tile_m=tile_m,
+    return jax.tree.map(
+        lambda w: cluster_agg(w, weights, num_clusters, interpret=interpret,
+                              tile_m=tile_m),
+        tree,
     )

@@ -1,10 +1,9 @@
-"""Jitted wrappers: fused SGD / normalized update over flat arrays or pytrees."""
+"""Jitted wrappers: fused SGD / normalized update over arrays or pytrees."""
 from __future__ import annotations
 
 import functools
 
 import jax
-import jax.numpy as jnp
 
 from .kernel import normalized_update_pallas, sgd_update_pallas
 from .ref import normalized_update_ref, sgd_update_ref
@@ -27,15 +26,9 @@ def normalized_update(w_final, w_start, inv_theta: float, impl: str = "pallas",
     return normalized_update_pallas(w_final, w_start, inv_theta, tile_m=tile_m, interpret=interpret)
 
 
-def sgd_update_tree(params, grads, lr: float, impl: str = "pallas",
-                    interpret: bool = False, tile_m: int = 1024):
-    def per_leaf(w, g):
-        flat, gflat = w.reshape(-1), g.reshape(-1)
-        pad = (-flat.size) % tile_m
-        if pad:
-            flat = jnp.pad(flat, (0, pad))
-            gflat = jnp.pad(gflat, (0, pad))
-        out = sgd_update(flat, gflat, lr, impl=impl, interpret=interpret, tile_m=tile_m)
-        return out[: w.size].reshape(w.shape)
-
-    return jax.tree.map(per_leaf, params, grads)
+def sgd_update_tree(params, grads, lr: float, interpret: bool = False, tile_m: int = 1024):
+    """``w - lr * g`` on every leaf, each in its own layout (no flattening)."""
+    return jax.tree.map(
+        lambda w, g: sgd_update(w, g, lr, interpret=interpret, tile_m=tile_m),
+        params, grads,
+    )

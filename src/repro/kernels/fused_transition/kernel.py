@@ -1,29 +1,26 @@
 """Pallas TPU kernel: fused Lemma-1 transition  W <- W @ (V P^alpha B).
 
 The paper's inter-cluster aggregation event factors into three stages on the
-flattened (C, M) client-model matrix (M = model dim, typically huge):
+stacked client models W (C, ...):
 
-    Y  = V^T W        intra-cluster weighted reduce      (D, M)
-    Y' = (P^T)^a Y    alpha gossip rounds on clusters    (D, M)
-    W' = B^T Y'       broadcast back to cluster members  (C, M)
+    Y  = V^T W        intra-cluster weighted reduce      (D, ...)
+    Y' = (P^T)^a Y    alpha gossip rounds on clusters    (D, ...)
+    W' = B^T Y'       broadcast back to cluster members  (C, ...)
 
 Running these as separate kernels (``cluster_agg`` then ``gossip_mix`` then
-an einsum) writes and re-reads the (D, M) intermediate from HBM twice.  This
-kernel fuses all three on a VMEM-resident (C, TM) tile: the factor matrices
-``V^T`` (D, C), ``P`` (D, D) and ``B^T`` (C, D) are tiny and live in VMEM
-for every grid step, so HBM traffic is exactly one read + one write of W —
-the bandwidth lower bound for the transition.
+an einsum) writes and re-reads the (D, ...) intermediate from HBM twice.
+This kernel fuses all three on one VMEM block of every client's leaf: the
+factors ``V^T`` (D, C), ``P`` (D, D) and ``B^T`` (C, D) are tiny and sit in
+SMEM, read as scalars, so HBM traffic is exactly one read + one write of W
+(in place) — the bandwidth lower bound for the transition.  C and D are a
+handful, so the three stages are scalar-times-block FMAs on the VPU in f32.
 
 With ``alpha == 0`` the mixing stage is skipped and the kernel computes the
 intra-cluster event ``W @ (V B)`` instead.
 
-Block layout:
-    vt:      (D, C)   VMEM, replicated to every grid step
-    p:       (D, D)   VMEM, replicated
-    bt:      (C, D)   VMEM, replicated
-    w tile:  (C, TM)  VMEM, index (0, i)
-    out:     (C, TM)  VMEM, index (0, i)
-Grid: (M // TM,) — embarrassingly parallel over model tiles.
+Block layout (``_tiling.stream_rows``): each leaf is viewed as (C, n, l)
+without moving data and tiled as (C, tn, tl) over a (cdiv(n, tn),
+cdiv(l, tl)) grid — embarrassingly parallel over model positions.
 """
 from __future__ import annotations
 
@@ -31,28 +28,26 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from .._tiling import mix, stream_rows
 
 __all__ = ["fused_transition_kernel", "fused_transition_pallas"]
 
 
 def fused_transition_kernel(vt_ref, p_ref, bt_ref, w_ref, out_ref, *, alpha: int):
-    w = w_ref[...].astype(jnp.float32)          # (C, TM)
-    vt = vt_ref[...].astype(jnp.float32)        # (D, C)
-    y = jax.lax.dot_general(
-        vt, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )                                           # (D, TM) — never leaves VMEM
-    if alpha:
-        p = p_ref[...].astype(jnp.float32)      # (D, D)
-        for _ in range(alpha):
-            # column convention: new[d] = sum_j p[j, d] y[j]  (P^T y)
-            y = jax.lax.dot_general(
-                p, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-            )
-    bt = bt_ref[...].astype(jnp.float32)        # (C, D)
-    out_ref[...] = jax.lax.dot_general(
-        bt, y, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ).astype(out_ref.dtype)                     # (C, TM)
+    d, c = vt_ref.shape
+    # Y = V^T W, one client row at a time: D accumulators + one live row
+    y = [None] * d
+    for i in range(c):
+        w = w_ref[i].astype(jnp.float32)        # (tn, tl)
+        for k in range(d):
+            t = vt_ref[k, i] * w
+            y[k] = t if y[k] is None else y[k] + t
+    for _ in range(alpha):
+        # column convention: new[k] = sum_j p[j, k] y[j]  (P^T y)
+        y = [mix(p_ref, y, k, transpose=True) for k in range(d)]
+    for i in range(c):
+        out_ref[i] = mix(bt_ref, y, i).astype(out_ref.dtype)
 
 
 def fused_transition_pallas(
@@ -64,24 +59,17 @@ def fused_transition_pallas(
     tile_m: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """w: (C, M); vt: (D, C) = V^T; p: (D, D); bt: (C, D) = B^T. M % tile_m == 0."""
-    c, m = w.shape
+    """w: (C, ...) stacked leaf; vt: (D, C) = V^T; p: (D, D); bt: (C, D) = B^T.
+
+    ``tile_m`` caps the lane width of a block (a multiple of 128 on TPU)."""
+    c = w.shape[0]
     d = p.shape[0]
     if vt.shape != (d, c) or bt.shape != (c, d):
         raise ValueError(f"factor shapes {vt.shape}/{bt.shape} inconsistent with "
                          f"C={c}, D={d}")
-    if m % tile_m:
-        raise ValueError(f"M={m} must be divisible by tile_m={tile_m}")
-    return pl.pallas_call(
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    return stream_rows(
         functools.partial(fused_transition_kernel, alpha=alpha),
-        grid=(m // tile_m,),
-        in_specs=[
-            pl.BlockSpec((d, c), lambda i: (0, 0)),
-            pl.BlockSpec((d, d), lambda i: (0, 0)),
-            pl.BlockSpec((c, d), lambda i: (0, 0)),
-            pl.BlockSpec((c, tile_m), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((c, tile_m), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((c, m), w.dtype),
-        interpret=interpret,
-    )(vt, p, bt, w)
+        w, (f32(vt), f32(p), f32(bt)), c,
+        work_rows=d + 2, lane_tile=tile_m, interpret=interpret,
+    )

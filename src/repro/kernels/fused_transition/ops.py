@@ -5,7 +5,6 @@ import functools
 
 import jax
 
-from .._tiling import _tiled_tree_apply
 from .kernel import fused_transition_pallas
 from .ref import fused_transition_ref
 
@@ -15,17 +14,18 @@ __all__ = ["fused_transition", "fused_transition_tree"]
 @functools.partial(jax.jit, static_argnames=("alpha", "impl", "interpret", "tile_m"))
 def fused_transition(w, vt, p, bt, alpha: int = 1, impl: str = "pallas",
                      interpret: bool = False, tile_m: int = 512):
+    """Transition one stacked (C, ...) leaf (``impl="ref"``: 2-D (C, M) only)."""
     if impl == "ref":
         return fused_transition_ref(w, vt, p, bt, alpha)
     return fused_transition_pallas(w, vt, p, bt, alpha, tile_m=tile_m,
                                    interpret=interpret)
 
 
-def fused_transition_tree(tree, vt, p, bt, alpha: int = 1, impl: str = "pallas",
+def fused_transition_tree(tree, vt, p, bt, alpha: int = 1,
                           interpret: bool = False, tile_m: int = 512):
     """Apply the fused transition to every leaf of a (C, ...) stacked pytree."""
-    return _tiled_tree_apply(
-        lambda flat: fused_transition(flat, vt, p, bt, alpha=alpha, impl=impl,
-                                      interpret=interpret, tile_m=tile_m),
-        tree, rows=vt.shape[1], tile_m=tile_m,
+    return jax.tree.map(
+        lambda w: fused_transition(w, vt, p, bt, alpha=alpha,
+                                   interpret=interpret, tile_m=tile_m),
+        tree,
     )

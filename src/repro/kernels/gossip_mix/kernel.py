@@ -1,19 +1,16 @@
 """Pallas TPU kernel: inter-cluster gossip mixing  Y <- Y @ P  (eq. 4).
 
-Stacked cluster models ``Y`` are a (D, M) matrix (D = #edge servers, M =
-flattened model dimension, typically huge).  One gossip round multiplies by
-the D x D mixing matrix ``P`` on the cluster axis.  This is a tall-skinny
-GEMM that is purely HBM-bandwidth-bound (arithmetic intensity ~= D flops per
-byte), so the kernel tiles M into VMEM-resident chunks and keeps the whole
-(tiny) P in VMEM; ``alpha`` rounds reuse the streamed tile alpha times before
-writing back — raising arithmetic intensity by alpha versus alpha separate
-GEMM launches (the XLA baseline).
+Stacked cluster models ``Y`` are a (D, ...) leaf (D = #edge servers).  One
+gossip round multiplies by the D x D mixing matrix ``P`` on the cluster
+axis.  This is purely HBM-bandwidth-bound (about D flops per byte), so the
+kernel streams VMEM blocks of every cluster's leaf and keeps the whole
+(tiny) P in SMEM; ``alpha`` rounds reuse the streamed block alpha times
+before writing it back in place — raising arithmetic intensity by alpha
+versus alpha separate passes (the XLA baseline).
 
-Block layout:
-    y tile:  (D, TM)  VMEM   (D <= 16 in our deployments; TM = 512 lanes)
-    p:       (D, D)   VMEM   (whole matrix, replicated to every grid step)
-    out:     (D, TM)  VMEM
-Grid: (M // TM,) — embarrassingly parallel over model tiles.
+Block layout (``_tiling.stream_rows``): each leaf is viewed as (D, n, l)
+without moving data and tiled as (D, tn, tl) over a (cdiv(n, tn),
+cdiv(l, tl)) grid — embarrassingly parallel over model positions.
 """
 from __future__ import annotations
 
@@ -21,20 +18,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from .._tiling import mix, stream_rows
 
 __all__ = ["gossip_mix_kernel", "gossip_mix_pallas"]
 
 
 def gossip_mix_kernel(p_ref, y_ref, out_ref, *, alpha: int):
-    y = y_ref[...].astype(jnp.float32)      # (D, TM)
-    p = p_ref[...].astype(jnp.float32)      # (D, D)
-    # alpha gossip rounds on the VMEM-resident tile: new[d] = sum_j p[j,d] y[j]
+    d = p_ref.shape[0]
+    y = [y_ref[k].astype(jnp.float32) for k in range(d)]     # D x (tn, tl)
+    # alpha gossip rounds on the VMEM-resident block: new[k] = sum_j p[j,k] y[j]
     for _ in range(alpha):
-        y = jax.lax.dot_general(
-            p, y, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )  # contraction over j: out[d, m] = sum_j p[j, d] y[j, m]
-    out_ref[...] = y.astype(out_ref.dtype)
+        y = [mix(p_ref, y, k, transpose=True) for k in range(d)]
+    for k in range(d):
+        out_ref[k] = y[k].astype(out_ref.dtype)
 
 
 def gossip_mix_pallas(
@@ -44,19 +41,12 @@ def gossip_mix_pallas(
     tile_m: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """y: (D, M); p: (D, D) with column convention (Y @ P^alpha). M % tile_m == 0."""
-    d, m = y.shape
-    if m % tile_m:
-        raise ValueError(f"M={m} must be divisible by tile_m={tile_m}")
-    grid = (m // tile_m,)
-    return pl.pallas_call(
+    """y: (D, ...) stacked leaf; p: (D, D) with column convention (Y @ P^alpha).
+
+    ``tile_m`` caps the lane width of a block (a multiple of 128 on TPU)."""
+    d = y.shape[0]
+    return stream_rows(
         functools.partial(gossip_mix_kernel, alpha=alpha),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((d, d), lambda i: (0, 0)),
-            pl.BlockSpec((d, tile_m), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((d, tile_m), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((d, m), y.dtype),
-        interpret=interpret,
-    )(p, y)
+        y, (p.astype(jnp.float32),), d,
+        work_rows=2 * d, lane_tile=tile_m, interpret=interpret,
+    )

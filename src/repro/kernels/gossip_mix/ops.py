@@ -5,7 +5,6 @@ import functools
 
 import jax
 
-from .._tiling import _tiled_tree_apply
 from .kernel import gossip_mix_pallas
 from .ref import gossip_mix_ref
 
@@ -14,18 +13,15 @@ __all__ = ["gossip_mix", "gossip_mix_tree"]
 
 @functools.partial(jax.jit, static_argnames=("alpha", "impl", "interpret", "tile_m"))
 def gossip_mix(y, p, alpha: int = 1, impl: str = "pallas", interpret: bool = False, tile_m: int = 512):
+    """Mix one stacked (D, ...) leaf (``impl="ref"``: 2-D (D, M) only)."""
     if impl == "ref":
         return gossip_mix_ref(y, p, alpha)
     return gossip_mix_pallas(y, p, alpha, tile_m=tile_m, interpret=interpret)
 
 
-def gossip_mix_tree(tree, p, alpha: int = 1, impl: str = "pallas", interpret: bool = False, tile_m: int = 512):
-    """Apply gossip mixing to every leaf of a (D, ...) stacked pytree.
-
-    Leaves are flattened to (D, M); M is padded up to the tile size only when
-    it is not already a multiple of it."""
-    return _tiled_tree_apply(
-        lambda flat: gossip_mix(flat, p, alpha=alpha, impl=impl,
-                                interpret=interpret, tile_m=tile_m),
-        tree, rows=p.shape[0], tile_m=tile_m,
+def gossip_mix_tree(tree, p, alpha: int = 1, interpret: bool = False, tile_m: int = 512):
+    """Apply gossip mixing to every leaf of a (D, ...) stacked pytree."""
+    return jax.tree.map(
+        lambda y: gossip_mix(y, p, alpha=alpha, interpret=interpret, tile_m=tile_m),
+        tree,
     )

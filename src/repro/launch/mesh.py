@@ -18,17 +18,13 @@ from repro.sharding.rules import MeshAxes
 
 __all__ = [
     "make_production_mesh", "make_test_mesh", "mesh_axes_for",
-    "make_client_mesh", "resolve_client_mesh",
+    "make_client_mesh", "resolve_client_mesh", "shard_clients",
     "make_cluster_mesh", "resolve_cluster_mesh",
 ]
 
 
 def _auto_axis_types(n: int) -> dict:
-    """``axis_types`` kwarg when available; jax < 0.5 has no AxisType."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -86,6 +82,17 @@ def resolve_client_mesh(spec, num_clients: int, axis_name: str = "data"):
             return make_client_mesh(num_clients, axis_name)
         return None
     raise ValueError(f"mesh must be None, 'auto', or a jax Mesh, got {spec!r}")
+
+
+def shard_clients(tree, mesh: jax.sharding.Mesh, axis_name: str = "data"):
+    """Place every stacked ``(C, ...)`` leaf one client per ``axis_name`` index.
+
+    The layout the mesh round step consumes and returns: state placed this
+    way before the first step keeps one compiled program and in-place
+    donation from the first step on.
+    """
+    sharding = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(axis_name))
+    return jax.tree.map(lambda x: jax.device_put(x, sharding), tree)
 
 
 def make_cluster_mesh(num_clusters: int, axis_name: str = "cluster") -> jax.sharding.Mesh:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import enable_compile_cache
 from repro.models import CausalLM
 
 
@@ -139,6 +140,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mixtral-8x7b")
     ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=16)
@@ -156,6 +158,7 @@ def main(argv=None):
                     help="'auto' to shard the cluster-replica stack across "
                          "a cluster mesh when enough devices exist")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.scenario is not None:
         server, done, _ = serve_scenario(

@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.core.config import ExecSpec, FleetSpec, ModelSpec, RunConfig
 from repro.core.runtime import make_run
 from repro.data.synthetic import SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.models import CausalLM
 
 
@@ -170,6 +171,7 @@ def main(argv=None):
     ap.add_argument("--save-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.scenario is not None:
         return run_scenario(args)

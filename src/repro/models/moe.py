@@ -124,14 +124,12 @@ def moe_mlp(x: jax.Array, params: dict, *, num_experts_per_tok: int, capacity_fa
                     out, aux = batched(xb)
                     return out, jax.lax.pmean(aux, tuple(axes))
 
-                from repro.sharding.compat import shard_map_compat
-
-                return shard_map_compat(
+                return jax.shard_map(
                     local_fn, mesh=mesh,
                     in_specs=(jax.sharding.PartitionSpec(bspec, None, None),),
                     out_specs=(jax.sharding.PartitionSpec(bspec, None, None),
                                jax.sharding.PartitionSpec()),
-                    axis_names=frozenset(axes),
+                    axis_names=frozenset(axes), check_vma=False,
                 )(x)
         return batched(x)
 

@@ -143,13 +143,17 @@ class _FederatedMixin:
         one — the staged slot is left untouched, the server keeps serving
         its last-good double-buffered weights, ``rejected`` is incremented,
         and ``False`` is returned.  A missing runtime is still a
-        ``ValueError``: that is a wiring bug, not a fault.
+        ``ValueError``: that is a wiring bug, not a fault.  So is an error
+        of the device itself (``jax.errors.JaxRuntimeError``, e.g. out of
+        memory): it propagates, since the server could not serve either.
         """
         rt = runtime or self._runtime
         if rt is None:
             raise ValueError("no runtime attached; pass one or construct with runtime=")
         try:
             self.publish(rt.cluster_params())
+        except jax.errors.JaxRuntimeError:
+            raise
         except Exception:
             self.rejected += 1
             return False
@@ -297,6 +301,11 @@ class ContinuousFederatedServer(_FederatedMixin, ContinuousServer):
         self._runtime = runtime
         self.rejected = 0
         self._buf = ReplicaBuffer(cluster_params, place=place)
+        if self.mesh is not None:
+            # the slot pool starts replicated on the mesh, the sharding the
+            # programs hand it back with, so no admission recompiles
+            self._state = jax.device_put(self._state, jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec()))
 
     # -- weight hooks ---------------------------------------------------------
     def _weights(self):

@@ -245,7 +245,4 @@ def build_slot_programs(model: CausalLM, *, temperature: float, gen_cap: int,
 
 def compile_count(fn) -> int:
     """Number of distinct shapes a jitted program has compiled for."""
-    try:
-        return int(fn._cache_size())
-    except AttributeError:  # pragma: no cover - older jax
-        return -1
+    return int(fn._cache_size())

@@ -98,9 +98,7 @@ def make_decode_impl(
             out = (acc_g / jnp.maximum(l_g, 1e-30)[..., None]).astype(q.dtype)
             return out, kc, vc, sp
 
-        from repro.sharding.compat import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(
@@ -118,6 +116,7 @@ def make_decode_impl(
                 P(batch_spec, seq_spec, None, None),
                 P(seq_spec),
             ),
+            check_vma=False,
         )(q1, k_cache, v_cache, slot_pos, q_pos, k_new, v_new)
 
     return impl

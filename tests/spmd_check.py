@@ -197,6 +197,46 @@ def check_lm_collective_mesh():
     print("lm_collective_mesh OK")
 
 
+def check_round_scheduler_mesh():
+    """``make_run`` with ``mesh="auto"`` places the client state one client
+    per device before the first step, so the round step compiles once and
+    every step keeps that layout."""
+    from repro.core.config import ExecSpec, ModelSpec, RunConfig
+    from repro.core.runtime import make_run
+    from repro.data import FederatedLM
+    from repro.models import CausalLM
+    from repro.models.config import ArchConfig
+
+    C = 4
+    cfg = ArchConfig(
+        name="spmd-round", family="dense", num_layers=1, d_model=32, d_ff=64,
+        vocab_size=128, num_heads=2, num_kv_heads=1, head_dim=16,
+        dtype="float32", remat=False, attn_chunk=16, tie_embeddings=True,
+    )
+    ds = FederatedLM.generate_clustered(C, 8, 16, 128, C, seed=0)
+    runtime = make_run(RunConfig(
+        model=ModelSpec(instance=CausalLM(cfg)),
+        exec=ExecSpec(scheduler="round", backend="auto", tau1=2, tau2=1,
+                      alpha=1, mesh="auto"),
+        num_clients=C, num_clusters=C,
+    ))
+    sched = runtime.scheduler
+    assert sched.backend.name == "collective" and sched.mesh is not None
+
+    def one_per_device(tree):
+        return all(len(x.addressable_shards) == C
+                   and all(s.data.shape[0] == 1 for s in x.addressable_shards)
+                   for x in jax.tree.leaves(tree))
+
+    assert one_per_device(sched.params)
+    for _ in range(3):
+        ev = runtime.step(lambda k: ds.stacked_batch(1, np.random.default_rng(k)))
+        assert np.all(np.isfinite(np.asarray(ev.losses)))
+    assert sched._round_step._cache_size() == 1, sched._round_step._cache_size()
+    assert one_per_device(sched.params)
+    print("round_scheduler_mesh OK")
+
+
 def check_continuous_mesh_serving():
     """Continuous serving with mesh-sharded replicas == the vmap fallback.
 
@@ -243,6 +283,9 @@ def check_continuous_mesh_serving():
         for r in batch:
             srv.submit(r)
         srv.run()
+        # no admission pattern or state placement recompiles a program
+        assert srv.compile_counts() == {"prefill": 2, "admit": 2, "decode": 1}, (
+            srv.compile_counts())
         return batch
 
     mesh = make_cluster_mesh(D)
@@ -262,4 +305,5 @@ if __name__ == "__main__":
         "decode_sharded": check_decode_sharded,
         "lm_collective_mesh": check_lm_collective_mesh,
         "continuous_mesh_serving": check_continuous_mesh_serving,
+        "round_scheduler_mesh": check_round_scheduler_mesh,
     }[sys.argv[1]]()

@@ -386,6 +386,15 @@ def test_serving_keeps_last_good_on_faulty_publish():
     for rt in (DyingRuntime(), PoisonedRuntime()):
         assert srv.sync_from(rt) is False
     assert srv.rejected == 2
+
+    class DeviceOutOfMemory:
+        def cluster_params(self):
+            raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    # a failing device is not a dying trainer: it must not pass as a reject
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        srv.sync_from(DeviceOutOfMemory())
+    assert srv.rejected == 2
     # the active slot never saw the bad stacks
     np.testing.assert_array_equal(
         np.asarray(jax.tree.leaves(srv.active_params)[0]),

@@ -1,11 +1,11 @@
-"""Federated-LM path: batched local SGD == the per-client loop, bit for bit.
+"""Federated-LM path: batched local SGD == the per-client loop.
 
 The batched stage (``core.local_update.build_local_update``) replaces the
 per-client Python dispatch loop everywhere — these tests pin the refactor:
 
-* the vmapped stage reproduces the sequential per-client reference
-  bitwise at fp32, standalone and through the round engine, the sync
-  scheduler, and masked participation;
+* the vmapped stage reproduces the sequential per-client reference at
+  fp32, standalone and through masked participation bitwise, and through
+  the round engine and the sync scheduler within fp32 rounding;
 * the fused-SGD kernel path (Pallas backend) is dense-equivalent;
 * bf16 client models track the fp32 trajectory within mixed-precision
   tolerance;
@@ -60,6 +60,20 @@ def _bitwise(tree_a, tree_b):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# The compiled round/sync programs and the per-client loop are different XLA
+# programs: fusion and reduction order differ, so fp32 results agree to
+# rounding, not bit for bit.  fp32 unit roundoff is 6e-8; a few SGD steps
+# and transitions of reordered sums stay well under 1e-6 on these O(1)
+# weights, while a wrong transition or update moves them by >= lr * grad,
+# orders of magnitude more.
+FP32_REORDER_ATOL = 1e-6
+
+
+def _close(tree_a, tree_b, atol=FP32_REORDER_ATOL):
+    for a, b in zip(jax.tree.leaves(tree_a), jax.tree.leaves(tree_b)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=atol, rtol=0)
+
+
 def test_batched_stage_bitwise_equals_sequential():
     """vmapped value_and_grad + update == C separate jitted dispatches."""
     model = CausalLM(_arch())
@@ -80,7 +94,8 @@ def test_batched_stage_bitwise_equals_sequential():
 
 
 def test_round_engine_bitwise_vs_python_loop():
-    """R=2 superstep == the naive loop (sequential stage + dense transitions)."""
+    """R=2 superstep == the naive loop (sequential stage + dense transitions),
+    within fp32 reordering (``FP32_REORDER_ATOL``)."""
     model = CausalLM(_arch())
     opt = optim.sgd(LR)
     fl = _fl()
@@ -106,11 +121,12 @@ def test_round_engine_bitwise_vs_python_loop():
                 k += 1
             p2 = backend.transition(p2, "intra")
         p2 = backend.transition(p2, "inter")
-    _bitwise(p1, p2)
+    _close(p1, p2)
 
 
 def test_sync_scheduler_bitwise_vs_sequential_reference():
-    """SyncScheduler iterations == sequential updates + scheduled transitions."""
+    """SyncScheduler iterations == sequential updates + scheduled transitions,
+    within fp32 reordering (``FP32_REORDER_ATOL``)."""
     from repro.core.runtime import SyncScheduler
 
     model = CausalLM(_arch())
@@ -134,7 +150,7 @@ def test_sync_scheduler_bitwise_vs_sequential_reference():
         event = proto.event_at(k)
         if event != "local":
             p_ref = backend.transition(p_ref, event)
-    _bitwise(sched.params, p_ref)
+    _close(sched.params, p_ref)
 
 
 def test_masked_participation_bitwise():
@@ -191,7 +207,7 @@ def test_bf16_round_tracks_fp32():
 
 
 def test_fused_sgd_stage_matches_dense_fp32():
-    """Pallas fused-SGD path (kernel + non-tiling fallback) == dense stage."""
+    """Pallas fused-SGD path (the kernel on every leaf) == dense stage."""
     model = CausalLM(_arch())
     opt = optim.sgd(LR)
     fl = _fl()
@@ -202,12 +218,10 @@ def test_fused_sgd_stage_matches_dense_fp32():
     assert not fused_sgd_applicable(opt, dense)
     assert fused_sgd_applicable(opt, pallas)
 
-    # tile_m=512: the embedding/projection leaves tile, the (C, 32) norm
-    # scales don't — both kernel and fallback branches execute
+    # leaves of every rank, from the (C, 32) norm scales up, stream in their
+    # own layout: no flattening, no dense fallback
     params = init_stacked(model, C, jax.random.PRNGKey(4))
-    sizes = {leaf.reshape(-1).shape[0] % 512 == 0
-             for leaf in jax.tree.leaves(params)}
-    assert sizes == {True, False}
+    assert {leaf.ndim for leaf in jax.tree.leaves(params)} >= {2, 4}
 
     batch = jax.tree.map(lambda x: x[0], _window(1))
     p_dense, _, l_dense = jax.jit(build_local_update(model, opt, backend=dense))(

@@ -196,3 +196,36 @@ def test_sgd_update_tree_matches_plain():
     ref = jax.tree.map(lambda p, g: p - 0.05 * g, params, grads)
     for k in params:
         np.testing.assert_allclose(out[k], ref[k], atol=1e-6)
+
+
+# -- partial edge blocks ------------------------------------------------------------
+
+@pytest.mark.parametrize("kernel", ["fused_transition", "gossip_mix", "cluster_agg", "sgd"])
+def test_partial_edge_blocks(monkeypatch, kernel):
+    """A leaf that no block divides, in rows or lanes, streams through partial
+    edge blocks: a small VMEM budget forces a (8, 128) block onto a
+    (C, 3, 37, 300) leaf, and the result still matches the oracle."""
+    from repro.kernels import _tiling
+
+    monkeypatch.setattr(_tiling, "_VMEM_BUDGET", 64 * 1024)
+    c, d = 8, 4
+    w = arr((c, 3, 37, 300))
+    flat = w.reshape(c, -1)
+    if kernel == "fused_transition":
+        vt, p, bt = _factors(c, d)
+        out = fused_transition(w, vt, p, bt, alpha=2, interpret=True, tile_m=128)
+        ref = fused_transition_ref(flat, vt, p, bt, 2)
+    elif kernel == "gossip_mix":
+        p = jnp.asarray(mixing_matrix(ring(c)), jnp.float32)
+        out = gossip_mix(w, p, alpha=2, interpret=True, tile_m=128)
+        ref = gossip_mix_ref(flat, p, 2)
+    elif kernel == "cluster_agg":
+        wt = jnp.asarray(RNG.uniform(0.1, 1.0, c), jnp.float32)
+        out = cluster_agg(w, wt, d, interpret=True, tile_m=128)
+        ref = cluster_agg_ref(flat, wt, d)
+    else:
+        g = arr(w.shape)
+        out = sgd_update(w, g, 0.1, interpret=True, tile_m=128)
+        ref = sgd_update_ref(w, g, 0.1)
+    assert out.shape[1:] == w.shape[1:]
+    np.testing.assert_allclose(np.asarray(out).reshape(ref.shape), ref, atol=1e-5)

@@ -51,3 +51,9 @@ def test_lm_collective_mesh_matches_emulation():
 def test_continuous_serving_mesh_matches_fallback():
     """Slot-pool decode on mesh-sharded cluster replicas == vmap fallback."""
     run_check("continuous_mesh_serving")
+
+
+@pytest.mark.slow
+def test_round_scheduler_mesh_compiles_once():
+    """mesh="auto": client state starts one client per device; one compile."""
+    run_check("round_scheduler_mesh")
