@@ -4,7 +4,7 @@ Trains the federated round engine for three steps and serves per-cluster
 replicas from the trained stack, through the same entry points the CLIs
 compose (``make_run`` as in ``launch/train.py``, ``ContinuousFederatedServer``
 as in ``launch/serve.py``).  Every width is granite-8b's published one; only
-the depth is cut (``--layers``).  Weights are random, data is the repo's
+the depth is cut (``LAYERS``).  Weights are random, data is the repo's
 synthetic clustered LM corpus, both made from ``--seed``.
 
     python chip_smoke.py                # one chip: pallas round step + serving
@@ -29,6 +29,10 @@ import jax
 import numpy as np
 
 ARCH = "granite-8b"
+# depth cut and training sequence length that fit one v5e (16 GB) with the
+# pallas round step; a second layer does not fit at seq 256 (PERF.md)
+LAYERS = 1
+SEQ = 512
 GEN_CAP = 32
 BUCKETS = (32, 64)
 CHUNK_STEPS = 8
@@ -39,6 +43,13 @@ BATCH = 1  # sequences per client per local step
 # rounding step (2^-8 relative, 2^-7 at the bottom of a binade).
 BF16_RTOL = 2.0 ** -7
 F32_ATOL = 1e-6
+# An f32 leaf on both sides: the kernel's MXU contractions at HIGHEST
+# precision and the dense einsum differ only in summation order; a single
+# bf16 MXU pass would miss by ~2^-9 relative and fail this.
+F32_RTOL = 1e-5
+# A fleet for the transition's MXU body: the dense anchor of the
+# state-scaling lane (MnistCNN, 1000 clients in 8 clusters), largest leaf.
+FLEET_CLIENTS, FLEET_CLUSTERS, FLEET_LEAF = 1000, 8, (320, 50)
 
 
 def check(name: str, ok: bool, detail: str = "") -> None:
@@ -121,22 +132,46 @@ def mlp_leaf(params):
     return params["blocks"]["pos0"]["ffn"]["w_gate"]
 
 
-def agree_with_dense(name: str, got, leaf, runtime) -> None:
-    """``got`` vs the dense Lemma-1 einsum of ``leaf`` (fp32 reference)."""
+def runtime_dense(runtime):
     from repro.core.backends import DenseBackend
 
     proto = runtime.scheduler._proto
-    dense = DenseBackend(proto.clusters, proto.P(), runtime.scheduler.fl.alpha)
+    return DenseBackend(proto.clusters, proto.P(), runtime.scheduler.fl.alpha)
+
+
+def agree_with_dense(name: str, got, leaf, dense, rtol: float = BF16_RTOL,
+                     why: str = "bf16 storage, f32 accumulation") -> None:
+    """``got`` vs the dense Lemma-1 einsum of ``leaf`` (fp32 reference)."""
     with jax.default_matmul_precision("highest"):
         ref = dense.transition({"w": leaf}, "inter")["w"]
     got = np.asarray(got, np.float32)
     ref = np.asarray(ref, np.float32)
     err = np.abs(got - ref)
-    bound = BF16_RTOL * np.abs(ref) + F32_ATOL
+    bound = rtol * np.abs(ref) + F32_ATOL
     check(name, bool(np.all(err <= bound)),
           f"leaf {leaf.shape} {leaf.dtype}: max |diff| {err.max():.3e}, "
           f"{np.count_nonzero(err)} of {err.size} elements differ, tolerance "
-          f"{BF16_RTOL:.4g}*|dense| + {F32_ATOL:g} (bf16 storage, f32 accumulation)")
+          f"{rtol:.4g}*|dense| + {F32_ATOL:g} ({why})")
+
+
+def fleet_transition(seed: int) -> None:
+    """The fused transition's MXU body, taken when C*D is past what the VPU
+    body unrolls, on one random f32 fleet leaf against the dense einsum."""
+    from repro.core import ClusterSpec, mixing_matrix, ring
+    from repro.core.backends import DenseBackend, PallasBackend
+    from repro.kernels.fused_transition.kernel import VPU_MAX_TERMS
+
+    c, d = FLEET_CLIENTS, FLEET_CLUSTERS
+    clusters = ClusterSpec.uniform(c, d)
+    p = mixing_matrix(ring(d), clusters.m_tilde())
+    pallas = PallasBackend(clusters, p, 1)
+    leaf = jax.random.normal(jax.random.PRNGKey(seed), (c,) + FLEET_LEAF)
+    check("fleet takes the MXU body", c * d > VPU_MAX_TERMS and not pallas.interpret,
+          f"C*D = {c * d} > {VPU_MAX_TERMS}, interpret={pallas.interpret}")
+    agree_with_dense("pallas transition == dense (inter, fleet)",
+                     pallas.transition({"w": leaf}, "inter")["w"], leaf,
+                     DenseBackend(clusters, p, 1), rtol=F32_RTOL,
+                     why="f32 storage and accumulation, summation order")
 
 
 def fit_max_batch(model, stack, free_bytes: int, candidates=(8, 4, 2, 1)):
@@ -207,12 +242,12 @@ def check_served(server, trace, done, vocab: int) -> None:
 def one_chip(args, devices) -> None:
     from repro.configs import get_config
 
-    cfg = dataclasses.replace(get_config(ARCH), num_layers=args.layers)
+    cfg = dataclasses.replace(get_config(ARCH), num_layers=LAYERS)
     info(f"{ARCH} widths: d_model {cfg.d_model}, heads {cfg.num_heads}/"
          f"{cfg.num_kv_heads}x{cfg.head_dim}, d_ff {cfg.d_ff}, vocab "
          f"{cfg.vocab_size}, {cfg.dtype}, remat {cfg.remat}; layers {cfg.num_layers}")
     runtime, dataset, source, losses = train(
-        cfg, clients=4, clusters=2, seq=args.seq, seed=args.seed, steps=3)
+        cfg, clients=4, clusters=2, seq=SEQ, seed=args.seed, steps=3)
     sched = runtime.scheduler
     backend = sched.backend
     check("backend", backend.name == "pallas" and backend.interpret is False,
@@ -229,7 +264,9 @@ def one_chip(args, devices) -> None:
     check("losses finite", bool(np.all(np.isfinite(losses))), f"{losses}")
     leaf = mlp_leaf(sched.params)
     agree_with_dense("pallas transition == dense (inter)",
-                     backend.transition({"w": leaf}, "inter")["w"], leaf, runtime)
+                     backend.transition({"w": leaf}, "inter")["w"], leaf,
+                     runtime_dense(runtime))
+    fleet_transition(args.seed)
     info(f"peak device memory after training {peak_gb(devices[0])}")
 
     stack = jax.eval_shape(runtime.cluster_params)
@@ -253,9 +290,9 @@ def four_chips(args, devices) -> None:
     replicas one per chip, each against its single-device counterpart."""
     from repro.configs import get_config
 
-    cfg = dataclasses.replace(get_config(ARCH), num_layers=args.layers)
+    cfg = dataclasses.replace(get_config(ARCH), num_layers=LAYERS)
     runtime, dataset, source, losses = train(
-        cfg, clients=4, clusters=4, seq=args.seq, seed=args.seed, steps=3,
+        cfg, clients=4, clusters=4, seq=SEQ, seed=args.seed, steps=3,
         mesh="auto")
     sched = runtime.scheduler
     check("backend", sched.backend.name == "collective" and sched.mesh is not None,
@@ -279,7 +316,7 @@ def four_chips(args, devices) -> None:
     leaf = mlp_leaf(sched.params)
     got = sched.backend.transition({"w": leaf}, "inter")["w"]
     agree_with_dense("collective transition == dense (inter)", got,
-                     jax.device_put(leaf, devices[0]), runtime)
+                     jax.device_put(leaf, devices[0]), runtime_dense(runtime))
 
     # on the host, so neither server keeps a second device copy of it
     stack = jax.device_get(runtime.cluster_params())
@@ -304,8 +341,6 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the four-chip path and what it is compared with")
-    ap.add_argument("--layers", type=int, default=1, help="depth cut (widths stay)")
-    ap.add_argument("--seq", type=int, default=512, help="training sequence length")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     count = 4 if args.four_chips else 1

@@ -242,7 +242,8 @@ class PallasBackend:
     """Tiled TPU kernels; fused V P^alpha B for the full transition.
 
     ``interpret`` defaults to True off-TPU so the same code path is testable
-    on CPU runners.
+    on CPU runners.  ``tile_m`` caps the lanes of a kernel block (a multiple
+    of 128); the kernels size the rest of a block from its VMEM budget.
     """
 
     name = "pallas"
@@ -262,7 +263,7 @@ class PallasBackend:
     def intra_cluster(self, stacked: PyTree, weights: jax.Array) -> PyTree:
         from repro.kernels import cluster_agg_tree
 
-        # the (g, TM)-tiled reduce assumes the contiguous uniform layout
+        # the kernel reduces client k*g+j into cluster k: contiguous, uniform
         _require_uniform_contiguous(self.clusters, "pallas")
         return cluster_agg_tree(
             stacked, jnp.asarray(weights, jnp.float32),
@@ -285,8 +286,8 @@ class PallasBackend:
 
         if event == "local":
             return stacked
-        # alpha=0 skips the mixing stage: V B.  The (D, M) intermediate stays
-        # in VMEM either way.
+        # alpha=0 skips the mixing stage: V B.  The (D, ...) intermediate
+        # stays in VMEM either way.
         alpha = self.alpha if event == "inter" else 0
         if weights is None:
             vt = self._vt

@@ -14,7 +14,7 @@ __all__ = ["cluster_agg", "cluster_agg_tree"]
 @functools.partial(jax.jit, static_argnames=("num_clusters", "impl", "interpret", "tile_m"))
 def cluster_agg(w, weights, num_clusters: int, impl: str = "pallas",
                 interpret: bool = False, tile_m: int = 512):
-    """Aggregate one stacked (C, ...) leaf (``impl="ref"``: 2-D (C, M) only)."""
+    """Aggregate one stacked (C, ...) leaf.  ``tile_m`` caps a block's lanes."""
     if impl == "ref":
         return cluster_agg_ref(w, weights, num_clusters)
     return cluster_agg_pallas(w, weights, num_clusters, tile_m=tile_m, interpret=interpret)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .._tiling import block_shape
+from .._tiling import block_shape, compiler_params
 
 __all__ = ["sgd_update_pallas", "normalized_update_pallas"]
 
@@ -45,8 +45,8 @@ def _tiled_call(kernel, a: jax.Array, b: jax.Array, tile_m: int, interpret: bool
     n = a2.shape[0]
     isz = max(a2.dtype.itemsize, b2.dtype.itemsize)
     # two operands and one output, double-buffered, plus three f32 rows
-    tn, tl = block_shape(n, l, lane_tile=tile_m, itemsize=isz,
-                         bytes_per_pos=6 * isz + 12)
+    tn, tl, vmem_bytes = block_shape(n, l, lane_tile=tile_m, itemsize=isz,
+                                     bytes_per_pos=6 * isz + 12)
     spec = pl.BlockSpec((tn, tl), lambda i, j: (i, j))
     out = pl.pallas_call(
         kernel,
@@ -55,6 +55,7 @@ def _tiled_call(kernel, a: jax.Array, b: jax.Array, tile_m: int, interpret: bool
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((n, l), a.dtype),
         input_output_aliases={0: 0} if in_place else {},
+        compiler_params=compiler_params(vmem_bytes),
         interpret=interpret,
     )(a2, b2)
     return out.reshape(shape)

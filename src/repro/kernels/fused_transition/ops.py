@@ -14,7 +14,7 @@ __all__ = ["fused_transition", "fused_transition_tree"]
 @functools.partial(jax.jit, static_argnames=("alpha", "impl", "interpret", "tile_m"))
 def fused_transition(w, vt, p, bt, alpha: int = 1, impl: str = "pallas",
                      interpret: bool = False, tile_m: int = 512):
-    """Transition one stacked (C, ...) leaf (``impl="ref"``: 2-D (C, M) only)."""
+    """Transition one stacked (C, ...) leaf.  ``tile_m`` caps a block's lanes."""
     if impl == "ref":
         return fused_transition_ref(w, vt, p, bt, alpha)
     return fused_transition_pallas(w, vt, p, bt, alpha, tile_m=tile_m,

@@ -13,7 +13,7 @@ __all__ = ["gossip_mix", "gossip_mix_tree"]
 
 @functools.partial(jax.jit, static_argnames=("alpha", "impl", "interpret", "tile_m"))
 def gossip_mix(y, p, alpha: int = 1, impl: str = "pallas", interpret: bool = False, tile_m: int = 512):
-    """Mix one stacked (D, ...) leaf (``impl="ref"``: 2-D (D, M) only)."""
+    """Mix one stacked (D, ...) leaf.  ``tile_m`` caps a block's lanes."""
     if impl == "ref":
         return gossip_mix_ref(y, p, alpha)
     return gossip_mix_pallas(y, p, alpha, tile_m=tile_m, interpret=interpret)

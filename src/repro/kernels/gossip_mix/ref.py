@@ -8,5 +8,5 @@ def gossip_mix_ref(y: jax.Array, p: jax.Array, alpha: int = 1) -> jax.Array:
     out = y.astype(jnp.float32)
     pf = p.astype(jnp.float32)
     for _ in range(alpha):
-        out = jnp.einsum("jm,jd->dm", out, pf)
+        out = jnp.einsum("j...,jd->d...", out, pf)
     return out.astype(y.dtype)

@@ -95,6 +95,7 @@ def _factors(c, d, topo=ring):
     (8, 4, 512, 1),
     (16, 4, 1024, 2),
     (20, 5, 512, 3),
+    (96, 8, 512, 1),   # C*D > VPU_MAX_TERMS: the MXU body
 ])
 def test_fused_transition_sweep(c, d, m, alpha):
     vt, p, bt = _factors(c, d)
@@ -200,7 +201,8 @@ def test_sgd_update_tree_matches_plain():
 
 # -- partial edge blocks ------------------------------------------------------------
 
-@pytest.mark.parametrize("kernel", ["fused_transition", "gossip_mix", "cluster_agg", "sgd"])
+@pytest.mark.parametrize("kernel", ["fused_transition", "fused_transition_mxu",
+                                    "gossip_mix", "cluster_agg", "sgd"])
 def test_partial_edge_blocks(monkeypatch, kernel):
     """A leaf that no block divides, in rows or lanes, streams through partial
     edge blocks: a small VMEM budget forces a (8, 128) block onto a
@@ -208,10 +210,10 @@ def test_partial_edge_blocks(monkeypatch, kernel):
     from repro.kernels import _tiling
 
     monkeypatch.setattr(_tiling, "_VMEM_BUDGET", 64 * 1024)
-    c, d = 8, 4
+    c, d = (40, 4) if kernel == "fused_transition_mxu" else (8, 4)
     w = arr((c, 3, 37, 300))
     flat = w.reshape(c, -1)
-    if kernel == "fused_transition":
+    if kernel.startswith("fused_transition"):
         vt, p, bt = _factors(c, d)
         out = fused_transition(w, vt, p, bt, alpha=2, interpret=True, tile_m=128)
         ref = fused_transition_ref(flat, vt, p, bt, 2)
@@ -229,3 +231,49 @@ def test_partial_edge_blocks(monkeypatch, kernel):
         ref = sgd_update_ref(w, g, 0.1)
     assert out.shape[1:] == w.shape[1:]
     np.testing.assert_allclose(np.asarray(out).reshape(ref.shape), ref, atol=1e-5)
+
+
+def test_reference_takes_the_kernel_leaf_shape():
+    """One contract per entry point: ``impl="ref"`` takes (rows, ...) too."""
+    c, d = 8, 4
+    w = arr((c, 3, 5, 7))
+    vt, p, bt = _factors(c, d)
+    wt = jnp.asarray(RNG.uniform(0.1, 1.0, c), jnp.float32)
+    pc = jnp.asarray(mixing_matrix(ring(c)), jnp.float32)
+    pairs = [
+        (fused_transition(w, vt, p, bt, alpha=1, impl="ref"),
+         fused_transition(w, vt, p, bt, alpha=1, interpret=True)),
+        (gossip_mix(w, pc, alpha=2, impl="ref"),
+         gossip_mix(w, pc, alpha=2, interpret=True)),
+        (cluster_agg(w, wt, d, impl="ref"),
+         cluster_agg(w, wt, d, interpret=True)),
+    ]
+    for ref, out in pairs:
+        assert ref.shape == out.shape
+        np.testing.assert_allclose(out, ref, atol=1e-5)
+
+
+# -- block sizing ---------------------------------------------------------------------
+
+def test_block_shape_narrows_lanes_before_rows():
+    """A fleet's block keeps one whole sublane tile of rows: the lanes give
+    way first, down to 128, and the VMEM limit is raised for what is left."""
+    from repro.kernels._tiling import _VMEM_BUDGET, block_shape
+
+    c, d = 1000, 8
+    per_pos = 2 * 2 * c * 4 + 4 * (2 * c + d)       # f32 transition, MXU body
+    tn, tl, vmem = block_shape(4096, 14336, lane_tile=512, bytes_per_pos=per_pos,
+                               itemsize=4, max_rows=8)
+    assert (tn, tl) == (8, 128)
+    assert vmem == per_pos * 8 * 128 > _VMEM_BUDGET
+    # a handful of clients fills the budget with rows at the full lane tile
+    tn, tl, vmem = block_shape(4096, 14336, lane_tile=512, bytes_per_pos=2 * 8 * 2 + 16,
+                               itemsize=2)
+    assert tl == 512 and tn % 16 == 0 and tn > 16 and vmem <= _VMEM_BUDGET
+
+
+def test_block_shape_refuses_a_block_over_the_vmem_limit():
+    from repro.kernels._tiling import block_shape
+
+    with pytest.raises(ValueError, match="VMEM"):
+        block_shape(320, 50, lane_tile=512, bytes_per_pos=24 * 10_000, itemsize=4)
