@@ -30,6 +30,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from .. import spans
+
 PyTree = Any
 
 __all__ = [
@@ -72,13 +74,15 @@ def build_local_update(model, opt, *, backend=None, tile_m: int = 1024):
         return jax.value_and_grad(model.loss)(p, b)
 
     def local_update(params, opt_state, batch):
-        losses, grads = jax.vmap(client_grads)(params, batch)
-        if use_fused:
-            params = sgd_update_tree(
-                params, grads, opt.lr, interpret=backend.interpret, tile_m=tile_m
-            )
-        else:
-            params, opt_state = jax.vmap(opt.update)(params, grads, opt_state)
+        with jax.named_scope(spans.FORWARD_BACKWARD):
+            losses, grads = jax.vmap(client_grads)(params, batch)
+        with jax.named_scope(spans.OPTIMIZER):
+            if use_fused:
+                params = sgd_update_tree(
+                    params, grads, opt.lr, interpret=backend.interpret, tile_m=tile_m
+                )
+            else:
+                params, opt_state = jax.vmap(opt.update)(params, grads, opt_state)
         return params, opt_state, losses
 
     return local_update

@@ -51,6 +51,7 @@ from typing import Any
 
 import jax
 
+from .. import spans
 from ..optim import Optimizer
 from .sdfeel import FLSpec
 
@@ -139,8 +140,10 @@ def build_fl_round_step(model, opt: Optimizer, fl: FLSpec, backend=None,
 
         def segment(c, seg_batches):
             # tau1 local iterations then one intra-cluster aggregation
-            (params, opt_state), losses = jax.lax.scan(local_iter, c, seg_batches)
-            params = backend.transition(params, "intra", weights=w)
+            with jax.named_scope(spans.LOCAL_UPDATE):
+                (params, opt_state), losses = jax.lax.scan(local_iter, c, seg_batches)
+            with jax.named_scope(spans.TRANSITION_INTRA):
+                params = backend.transition(params, "intra", weights=w)
             return (params, opt_state), losses
 
         (params, opt_state), losses = jax.lax.scan(segment, carry, seg)
@@ -149,7 +152,8 @@ def build_fl_round_step(model, opt: Optimizer, fl: FLSpec, backend=None,
         # aggregate re-aggregates to itself): T_intra @ T_inter = T_inter.
         # Under participation both factors use the same per-round weights, so
         # the composition stays exact round by round.
-        params = backend.transition(params, "inter", weights=w, p=p)
+        with jax.named_scope(spans.TRANSITION_INTER):
+            params = backend.transition(params, "inter", weights=w, p=p)
         return (params, opt_state), losses.reshape(tau1 * tau2)
 
     ipr = tau1 * tau2

@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from .backends import collective_supported, resolve_backend
 from .config import FleetSpec, RunConfig
 from .latency import LatencyModel
@@ -411,34 +412,31 @@ class SyncScheduler:
         )
 
         def local_sgd(params, batch):
-            params, _, _ = local_stage(params, (), batch)
+            with jax.named_scope(spans.LOCAL_UPDATE):
+                params, _, _ = local_stage(params, (), batch)
             return params
 
+        transition_scope = {"intra": spans.TRANSITION_INTRA,
+                            "inter": spans.TRANSITION_INTER}
+
         def make_step(event):
+            def transition(params, **kw):
+                if event == "local":
+                    return params
+                with jax.named_scope(transition_scope[event]):
+                    return self.backend.transition(params, event, **kw)
+
             def fused(params, batch):
-                params = local_sgd(params, batch)
-                if event != "local":
-                    params = self.backend.transition(params, event)
-                return params
+                return transition(local_sgd(params, batch))
 
             def fused_sampled(params, batch, weights):
-                params = local_sgd(params, batch)
-                if event != "local":
-                    params = self.backend.transition(
-                        params, event, weights=weights
-                    )
-                return params
+                return transition(local_sgd(params, batch), weights=weights)
 
             def fused_faulted(params, batch, weights, p):
                 # p is consumed only by the inter transition (backends ignore
                 # it elsewhere); weights fold crashed clients/uplink drops
                 # into the same renormalized vector participation uses
-                params = local_sgd(params, batch)
-                if event != "local":
-                    params = self.backend.transition(
-                        params, event, weights=weights, p=p
-                    )
-                return params
+                return transition(local_sgd(params, batch), weights=weights, p=p)
 
             if self.faults is not None:
                 return jax.jit(fused_faulted, donate_argnums=0)
@@ -912,9 +910,10 @@ class RoundScheduler:
     def _offload_step(self, k: int, batch_source) -> StepEvent:
         from ..state import sub_weights
 
-        stacked, staged = self._superstep_batches(k, batch_source)
-        res = self._residency_for_step(k)
-        buf = self.store.gather(res, staged)
+        with jax.profiler.TraceAnnotation(spans.STAGE):
+            stacked, staged = self._superstep_batches(k, batch_source)
+            res = self._residency_for_step(k)
+            buf = self.store.gather(res, staged)
         # sgd's state is () so per-superstep re-init is free; stateful
         # optimizers reset between supersteps under offload (documented)
         opt_buf = self.optimizer.init(buf)
@@ -924,7 +923,8 @@ class RoundScheduler:
             np.tile(sub_weights(w_full, res), (self.rounds_per_step, 1)),
             jnp.float32,
         )
-        buf, _, losses = self._round_step(buf, opt_buf, stacked, weights)
+        with jax.profiler.TraceAnnotation(spans.DISPATCH):
+            buf, _, losses = self._round_step(buf, opt_buf, stacked, weights)
         self.store.scatter(res, buf)
         if self.profile is None:
             dt = self.rounds_per_step * self._round_time
@@ -975,10 +975,11 @@ class RoundScheduler:
     def _fault_step(self, k: int, stacked) -> StepEvent:
         r0 = (k - 1) * self.rounds_per_step
         w_np, masks, mixing = self._fault_operands(r0)
-        self.params, self.opt_state, losses = self._round_step(
-            self.params, self.opt_state, stacked,
-            jnp.asarray(w_np, jnp.float32), jnp.asarray(mixing, jnp.float32),
-        )
+        with jax.profiler.TraceAnnotation(spans.DISPATCH):
+            self.params, self.opt_state, losses = self._round_step(
+                self.params, self.opt_state, stacked,
+                jnp.asarray(w_np, jnp.float32), jnp.asarray(mixing, jnp.float32),
+            )
         if self.profile is None:
             dt = self.rounds_per_step * self._round_time
         else:
@@ -1003,7 +1004,8 @@ class RoundScheduler:
     def step(self, k: int, batch_source) -> StepEvent:
         if not self.store.resident:
             return self._offload_step(k, batch_source)
-        stacked = self._superstep_batches(k, batch_source)
+        with jax.profiler.TraceAnnotation(spans.STAGE):
+            stacked = self._superstep_batches(k, batch_source)
         if self.faults is not None:
             return self._fault_step(k, stacked)
         if self._sampling:
@@ -1014,15 +1016,17 @@ class RoundScheduler:
                 self.plan.stacked_weights(r0, self.rounds_per_step),
                 jnp.float32,
             )
-            self.params, self.opt_state, losses = self._round_step(
-                self.params, self.opt_state, stacked, weights
-            )
+            with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                self.params, self.opt_state, losses = self._round_step(
+                    self.params, self.opt_state, stacked, weights
+                )
             dt = sum(self._masked_round_time(r0 + i)
                      for i in range(self.rounds_per_step))
         else:
-            self.params, self.opt_state, losses = self._round_step(
-                self.params, self.opt_state, stacked
-            )
+            with jax.profiler.TraceAnnotation(spans.DISPATCH):
+                self.params, self.opt_state, losses = self._round_step(
+                    self.params, self.opt_state, stacked
+                )
             if self._schedule is not None:
                 r0 = (k - 1) * self.rounds_per_step
                 dt = sum(self._round_time_at(r0 + i)

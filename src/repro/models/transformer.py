@@ -25,6 +25,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from .config import ArchConfig
 from .layers import (
     blocked_causal_attention,
@@ -181,11 +182,12 @@ def _apply_layer(
     h = rms_norm(x, p["ln_mix"], cfg.norm_eps)
     if kind == "attn":
         window = cfg.window_for_layer(idx_in_period, long_context)
-        mix, new_cache = _attention(
-            p["attn"], h, cfg,
-            window=window, positions=positions, cache=cache,
-            q_pos=q_pos, return_cache=return_cache, decode_impl=decode_impl,
-        )
+        with jax.named_scope(spans.ATTENTION):
+            mix, new_cache = _attention(
+                p["attn"], h, cfg,
+                window=window, positions=positions, cache=cache,
+                q_pos=q_pos, return_cache=return_cache, decode_impl=decode_impl,
+            )
     else:
         if cache is not None and q_pos is not None:
             mix, new_cache = mamba_decode_step(p["mamba"], h, cfg, cache)
@@ -202,14 +204,15 @@ def _apply_layer(
 
     if cfg.d_ff:
         h = rms_norm(x, p["ln_ffn"], cfg.norm_eps)
-        if cfg.is_moe_layer(idx_in_period):
-            out, aux = moe_mlp(
-                h, p["ffn"],
-                num_experts_per_tok=cfg.num_experts_per_tok,
-                capacity_factor=cfg.moe_capacity_factor,
-            )
-        else:
-            out = gated_mlp(h, p["ffn"])
+        with jax.named_scope(spans.MLP):
+            if cfg.is_moe_layer(idx_in_period):
+                out, aux = moe_mlp(
+                    h, p["ffn"],
+                    num_experts_per_tok=cfg.num_experts_per_tok,
+                    capacity_factor=cfg.moe_capacity_factor,
+                )
+            else:
+                out = gated_mlp(h, p["ffn"])
         if cfg.use_post_norm:
             out = rms_norm(out, p["ln_ffn_post"], cfg.norm_eps)
         x = x + out
@@ -263,6 +266,7 @@ class CausalLM:
         return params
 
     # -- embedding / head -----------------------------------------------------
+    @jax.named_scope(spans.EMBED)
     def embed_tokens(self, params, tokens, frontend_embeds=None):
         cfg = self.cfg
         if cfg.modality == "audio" and cfg.num_codebooks > 1:
@@ -281,6 +285,11 @@ class CausalLM:
             x = jnp.concatenate([frontend_embeds.astype(x.dtype), x[:, f:]], axis=1)
         return x
 
+    @jax.named_scope(spans.LM_HEAD)
+    def final_norm(self, params, x):
+        return rms_norm(x, params["ln_final"], self.cfg.norm_eps)
+
+    @jax.named_scope(spans.LM_HEAD)
     def logits(self, params, x):
         cfg = self.cfg
         if cfg.tie_embeddings:
@@ -326,14 +335,12 @@ class CausalLM:
     # -- public API -------------------------------------------------------------
     def forward(self, params, batch) -> tuple[jax.Array, jax.Array]:
         """batch: {tokens (B,S) or (B,K,S), frontend_embeds?} -> (logits, aux)."""
-        cfg = self.cfg
         tokens = batch["tokens"]
         s = tokens.shape[-1]
         x = self.embed_tokens(params, tokens, batch.get("frontend_embeds"))
         positions = jnp.arange(s, dtype=jnp.int32)
         x, aux, _ = self._run_stack(params, x, positions, return_cache=False)
-        x = rms_norm(x, params["ln_final"], cfg.norm_eps)
-        return self.logits(params, x), aux
+        return self.logits(params, self.final_norm(params, x)), aux
 
     def loss(self, params, batch) -> jax.Array:
         cfg = self.cfg
@@ -343,9 +350,9 @@ class CausalLM:
         if cfg.modality == "audio" and cfg.num_codebooks > 1:
             # logits (B,S,K,V); labels (B,K,S)
             logits = logits.transpose(0, 2, 1, 3)
-        logits = logits[..., :v]
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        with jax.named_scope(spans.LM_HEAD):
+            logp = jax.nn.log_softmax(logits[..., :v], axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
         mask = batch.get("loss_mask")
         if mask is None and cfg.frontend_tokens:
             m = jnp.ones(nll.shape, jnp.float32)
@@ -378,14 +385,12 @@ class CausalLM:
 
     def prefill(self, params, batch) -> tuple[jax.Array, PyTree]:
         """Full-sequence prefill: returns (last-position logits, cache)."""
-        cfg = self.cfg
         tokens = batch["tokens"]
         s = tokens.shape[-1]
         x = self.embed_tokens(params, tokens, batch.get("frontend_embeds"))
         positions = jnp.arange(s, dtype=jnp.int32)
         x, _, caches = self._run_stack(params, x, positions, return_cache=True)
-        x = rms_norm(x, params["ln_final"], cfg.norm_eps)
-        return self.logits(params, x[:, -1:, :]), caches
+        return self.logits(params, self.final_norm(params, x)[:, -1:, :]), caches
 
     def decode_hidden(self, params, token, cache, pos):
         """``decode_step`` up to (and including) the final norm.
@@ -418,8 +423,7 @@ class CausalLM:
             return x, new_caches
 
         x, new_cache = jax.lax.scan(block_fn, x, (params["blocks"], cache))
-        x = rms_norm(x, params["ln_final"], cfg.norm_eps)
-        return x, new_cache
+        return self.final_norm(params, x), new_cache
 
     def decode_step(self, params, token, cache, pos):
         """token: (B,) or (B,K); pos: scalar int32 (current position).

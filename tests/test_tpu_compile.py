@@ -7,8 +7,9 @@ checks that the kernel is in the program (``tpu_custom_call``) and that the
 leaf streams in its own layout: in place, with no relayout copy of the leaf
 (the temp buffer stays far below the leaf's size).
 
-The topology is described only inside the module fixture below, never while
-a module is imported: one process at a time may load the TPU library.
+The topology is described only inside the ``described_chip`` fixture
+(``tests/conftest.py``), never while a module is imported: one process at
+a time may load the TPU library.
 """
 import dataclasses
 
@@ -16,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.core.backends import PallasBackend
@@ -31,23 +31,6 @@ LEAVES = {
     "embed": (GRANITE.padded_vocab, GRANITE.d_model),
     "norm": (1, GRANITE.d_model),
 }
-
-
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # a described-chip executable is written to the cache but cannot be read back
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", was)
 
 
 def _backend():
@@ -70,13 +53,13 @@ def _leaf(rows, name, sharding):
 
 
 @pytest.mark.parametrize("name", sorted(LEAVES))
-def test_fused_transition_compiles(one_chip, name):
+def test_fused_transition_compiles(described_chip, name):
     backend = _backend()
     _compile(lambda w: backend.transition({"w": w}, "inter")["w"],
-             _leaf(C, name, one_chip))
+             _leaf(C, name, described_chip))
 
 
-def test_fused_transition_compiles_for_a_fleet(one_chip):
+def test_fused_transition_compiles_for_a_fleet(described_chip):
     """1000 clients in 8 clusters, the dense anchor of the state-scaling lane
     (MnistCNN, f32): the MXU body, one sublane tile of rows per block and a
     raised scoped-VMEM limit.  XLA lays this leaf out with the client dim
@@ -84,20 +67,20 @@ def test_fused_transition_compiles_for_a_fleet(one_chip):
     c, d = 1000, 8
     backend = PallasBackend(ClusterSpec.uniform(c, d), np.full((d, d), 1.0 / d), 1,
                             interpret=False)
-    w3 = jax.ShapeDtypeStruct((c, 320, 50), jnp.float32, sharding=one_chip)
+    w3 = jax.ShapeDtypeStruct((c, 320, 50), jnp.float32, sharding=described_chip)
     _compile(lambda w: backend.transition({"w": w}, "inter")["w"], w3, in_place=False)
 
 
-def test_fused_sgd_compiles(one_chip):
-    w = _leaf(C, "mlp", one_chip)
+def test_fused_sgd_compiles(described_chip):
+    w = _leaf(C, "mlp", described_chip)
     _compile(lambda w, g: sgd_update_tree({"w": w}, {"w": g}, 0.01)["w"], w, w)
 
 
-def test_gossip_mix_compiles(one_chip):
+def test_gossip_mix_compiles(described_chip):
     backend = _backend()
     p = np.full((D, D), 1.0 / D)
     _compile(lambda y: backend.inter_cluster({"y": y}, p, 1)["y"],
-             _leaf(D, "mlp", one_chip))
+             _leaf(D, "mlp", described_chip))
 
 
 def test_leaf_shapes_are_granite_widths():
