@@ -2,8 +2,10 @@
 
 Each kernel ships as ``<name>/{kernel.py, ops.py, ref.py}``: the Mosaic TPU
 kernel (pl.pallas_call + explicit VMEM BlockSpecs), a jitted wrapper, and a
-pure-jnp oracle.  On this CPU container the kernels are validated with
-``interpret=True``; on real TPUs pass ``interpret=False`` (default).
+pure-jnp oracle.  ``flash_attention`` has no ``kernel.py``: its wrapper runs
+the splash attention kernels that ship with JAX.  On this CPU container the
+kernels are validated with ``interpret=True``; on real TPUs pass
+``interpret=False`` (default).
 """
 from .gossip_mix import gossip_mix, gossip_mix_tree, gossip_mix_ref
 from .cluster_agg import cluster_agg, cluster_agg_tree, cluster_agg_ref

@@ -1,5 +1,4 @@
-from .ops import flash_attention
+from .ops import block_size, flash_attention
 from .ref import flash_attention_ref
-from .kernel import flash_attention_pallas
 
-__all__ = ["flash_attention", "flash_attention_ref", "flash_attention_pallas"]
+__all__ = ["block_size", "flash_attention", "flash_attention_ref"]

@@ -75,7 +75,6 @@ class ArchConfig:
     activation_dtype: Optional[str] = None
     remat: bool = True
     remat_policy: Literal["full", "dots"] = "full"  # dots: save matmul outputs
-    attn_impl: Literal["xla", "pallas"] = "xla"
     attn_chunk: int = 512                      # blocked-attention tile
 
     # ------------------------------------------------------------------

@@ -4,9 +4,11 @@ Attention comes in two executions:
 
 * ``blocked_causal_attention`` — flash-structured online-softmax over KV
   chunks using two nested ``lax.scan``s (O(chunk^2) memory, O(S^2) compute).
-  This is the XLA path used for training/prefill and for the CPU dry-run.
-  The Pallas kernel in ``repro.kernels.flash_attention`` implements the same
-  contract for real TPUs (with causal block skipping).
+  Training and prefill take it off the TPU, for sequence lengths the Pallas
+  kernel's blocks do not tile, and where the q chunks shard over the model
+  axis.  On a TPU they otherwise take the differentiable Pallas kernel in
+  ``repro.kernels.flash_attention`` (``models.transformer._flash_kernel``),
+  which keeps its tiles in VMEM and skips the blocks causality masks.
 * ``decode_attention`` — one query token against a KV cache, with the
   (numerator, denominator, max) stats exposed separately so the distribution
   layer can LSE-merge partial results across a sequence-sharded cache.

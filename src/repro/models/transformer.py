@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import spans
+from ..kernels.flash_attention import block_size, flash_attention
 from .config import ArchConfig
 from .layers import (
     blocked_causal_attention,
@@ -102,6 +103,18 @@ def _init_layer_params(rng, cfg: ArchConfig, idx_in_period: int) -> dict:
 # Per-layer application
 # ---------------------------------------------------------------------------
 
+def _flash_kernel(s: int):
+    """The Pallas flash-attention kernel for a full-sequence pass, where it
+    runs: on a TPU, for a sequence its blocks tile, on a host of one chip.
+    The compiler cannot partition a Mosaic kernel, and every program that
+    spans chips (the collective round step, the cluster-mesh server, the
+    launch layer's steps and their model-axis q chunks) needs a host of
+    several.  Otherwise None, and the pass takes ``blocked_causal_attention``."""
+    if jax.default_backend() != "tpu" or jax.device_count() != 1 or block_size(s) is None:
+        return None
+    return flash_attention
+
+
 def _attention(
     p: dict,
     x: jax.Array,
@@ -143,15 +156,22 @@ def _attention(
             )[:, None]
         new_cache = {"k": k_c, "v": v_c, "pos": pos_c}
     else:
-        from repro.sharding.context import model_axis_size
+        kernel = _flash_kernel(s)
+        if kernel is not None:
+            # positions is arange(s) on every path that gets here
+            # (CausalLM.forward, CausalLM.prefill), so the kernel's structural
+            # causal mask is the positional mask blocked_causal_attention builds
+            out = kernel(q, k, v, window=window, logit_cap=cfg.attn_logit_softcap)
+        else:
+            from repro.sharding.context import model_axis_size
 
-        ms = model_axis_size()
-        out = blocked_causal_attention(
-            q, k, v,
-            window=window, logit_cap=cfg.attn_logit_softcap,
-            chunk=cfg.attn_chunk, positions=positions,
-            shard_chunk=(ms > 1 and cfg.num_heads % ms != 0),
-        )
+            ms = model_axis_size()
+            out = blocked_causal_attention(
+                q, k, v,
+                window=window, logit_cap=cfg.attn_logit_softcap,
+                chunk=cfg.attn_chunk, positions=positions,
+                shard_chunk=(ms > 1 and cfg.num_heads % ms != 0),
+            )
         if return_cache:
             sc = min(window, s) if window is not None else s
             new_cache = {
