@@ -1,4 +1,5 @@
-"""Core neural layers: RMSNorm, RoPE, GQA attention (blocked + decode), MLP.
+"""Core neural layers: RMSNorm, RoPE, GQA attention (blocked + decode), MLP,
+and the training loss's softmax cross-entropy.
 
 Attention comes in two executions:
 
@@ -18,6 +19,7 @@ and ring-buffer caches via per-slot absolute positions.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -34,6 +36,7 @@ __all__ = [
     "dense",
     "init_dense",
     "softcap",
+    "softmax_cross_entropy",
 ]
 
 NEG_INF = -1e30
@@ -51,6 +54,64 @@ def softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
     if cap is None:
         return x
     return cap * jnp.tanh(x / cap)
+
+
+def softmax_cross_entropy(
+    logits: jax.Array, labels: jax.Array, *, vocab: int, softcap: Optional[float] = None
+) -> jax.Array:
+    """Per-position NLL of ``labels`` under ``softmax(softcap(logits))``.
+
+    logits: (..., V) with V >= ``vocab``; the columns at or above ``vocab``
+    are padding and take no probability.  labels: (...) int.  The logits are
+    upcast to float32 only inside the reductions, and the backward writes the
+    gradient straight in the logits' dtype, so no float32 copy of the
+    vocabulary-wide tensor is ever stored.  ``labels`` gets no gradient.
+    """
+    return _cross_entropy(logits, labels, vocab, softcap)
+
+
+def _capped_logits(logits, vocab, cap):
+    """float32 logits after the softcap, with the padding columns at -inf;
+    and tanh(logits / cap), or None without a softcap."""
+    x = logits.astype(jnp.float32)
+    t = None
+    if cap is not None:
+        t = jnp.tanh(x / cap)
+        x = cap * t
+    if vocab < x.shape[-1]:
+        x = jnp.where(jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1) < vocab,
+                      x, -jnp.inf)
+    return x, t
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _cross_entropy(logits, labels, vocab, cap):
+    return _cross_entropy_fwd(logits, labels, vocab, cap)[0]
+
+
+def _cross_entropy_fwd(logits, labels, vocab, cap):
+    x, _ = _capped_logits(logits, vocab, cap)
+    m = jnp.max(x, axis=-1, keepdims=True)
+    lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(x - m), axis=-1))
+    picked = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    picked = softcap(picked.astype(jnp.float32), cap)
+    return lse - picked, (logits, labels, lse)
+
+
+def _cross_entropy_bwd(vocab, cap, res, g):
+    logits, labels, lse = res
+    x, t = _capped_logits(logits, vocab, cap)
+    col = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    # exp(-inf) = 0 and no label: the padding columns' gradient is exactly 0
+    d = jnp.exp(x - lse[..., None]) - (col == labels[..., None]).astype(jnp.float32)
+    if t is not None:
+        d = d * (1.0 - t * t)
+    # Stored once in the logits' dtype: fused into the two gradient matmuls
+    # that read it, the exp would be recomputed for every tile of their output.
+    return jax.lax.optimization_barrier((d * g[..., None]).astype(logits.dtype)), None
+
+
+_cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0) -> jax.Array:

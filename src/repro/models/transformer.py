@@ -8,7 +8,9 @@ over stacked block parameters — keeping HLO size O(1) in depth for the
 wraps the block body when ``cfg.remat``.
 
 Three entry points:
-  * ``forward``      — full-sequence logits (training, and the prefill math)
+  * ``forward``      — full-sequence logits (the prefill math; ``loss`` takes
+                       the head's own-dtype output into
+                       ``layers.softmax_cross_entropy`` instead)
   * ``prefill``      — forward + KV/SSM cache construction
   * ``decode_step``  — one token against the cache (ring-buffer aware)
 
@@ -37,6 +39,7 @@ from .layers import (
     rms_norm,
     rope,
     softcap,
+    softmax_cross_entropy,
 )
 from .mamba import init_mamba_cache, init_mamba_params, mamba_decode_step, mamba_forward
 from .moe import init_moe_params, moe_mlp
@@ -309,17 +312,19 @@ class CausalLM:
     def final_norm(self, params, x):
         return rms_norm(x, params["ln_final"], self.cfg.norm_eps)
 
-    @jax.named_scope(spans.LM_HEAD)
-    def logits(self, params, x):
+    def _head(self, params, x):
+        """The head matmul, in the activations' dtype, before the softcap."""
         cfg = self.cfg
         if cfg.tie_embeddings:
             w = params["embed"]
-            out = jnp.einsum("bsd,vd->bsv", x, w.astype(x.dtype))  # upcast fp8 -> act
-        elif cfg.modality == "audio" and cfg.num_codebooks > 1:
-            out = jnp.einsum("bsd,kdv->bskv", x, params["head"].astype(x.dtype))
-        else:
-            out = dense(x, params["head"])
-        return softcap(out.astype(jnp.float32), cfg.final_logit_softcap)
+            return jnp.einsum("bsd,vd->bsv", x, w.astype(x.dtype))  # upcast fp8 -> act
+        if cfg.modality == "audio" and cfg.num_codebooks > 1:
+            return jnp.einsum("bsd,kdv->bskv", x, params["head"].astype(x.dtype))
+        return dense(x, params["head"])
+
+    @jax.named_scope(spans.LM_HEAD)
+    def logits(self, params, x):
+        return softcap(self._head(params, x).astype(jnp.float32), self.cfg.final_logit_softcap)
 
     # -- stacks ---------------------------------------------------------------
     def _run_stack(self, params, x, positions, *, return_cache=False):
@@ -353,26 +358,32 @@ class CausalLM:
         return x, aux, caches
 
     # -- public API -------------------------------------------------------------
-    def forward(self, params, batch) -> tuple[jax.Array, jax.Array]:
-        """batch: {tokens (B,S) or (B,K,S), frontend_embeds?} -> (logits, aux)."""
+    def _hidden(self, params, batch) -> tuple[jax.Array, jax.Array]:
+        """The final-normed hidden states (B, S, d) and the router aux loss."""
         tokens = batch["tokens"]
         s = tokens.shape[-1]
         x = self.embed_tokens(params, tokens, batch.get("frontend_embeds"))
         positions = jnp.arange(s, dtype=jnp.int32)
         x, aux, _ = self._run_stack(params, x, positions, return_cache=False)
-        return self.logits(params, self.final_norm(params, x)), aux
+        return self.final_norm(params, x), aux
+
+    def forward(self, params, batch) -> tuple[jax.Array, jax.Array]:
+        """batch: {tokens (B,S) or (B,K,S), frontend_embeds?} -> (logits, aux)."""
+        x, aux = self._hidden(params, batch)
+        return self.logits(params, x), aux
 
     def loss(self, params, batch) -> jax.Array:
         cfg = self.cfg
-        logits, aux = self.forward(params, batch)
+        x, aux = self._hidden(params, batch)
         labels = batch["labels"]
-        v = cfg.vocab_size
-        if cfg.modality == "audio" and cfg.num_codebooks > 1:
-            # logits (B,S,K,V); labels (B,K,S)
-            logits = logits.transpose(0, 2, 1, 3)
         with jax.named_scope(spans.LM_HEAD):
-            logp = jax.nn.log_softmax(logits[..., :v], axis=-1)
-            nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+            out = self._head(params, x)
+            if cfg.modality == "audio" and cfg.num_codebooks > 1:
+                # out (B,S,K,V); labels (B,K,S)
+                out = out.transpose(0, 2, 1, 3)
+            nll = softmax_cross_entropy(
+                out, labels, vocab=cfg.vocab_size, softcap=cfg.final_logit_softcap
+            )
         mask = batch.get("loss_mask")
         if mask is None and cfg.frontend_tokens:
             m = jnp.ones(nll.shape, jnp.float32)

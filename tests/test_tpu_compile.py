@@ -5,13 +5,15 @@ is described, not attached.  Each test compiles one kernel on one
 granite-8b parameter leaf through the tree helper the round step calls, and
 checks that the kernel is in the program (``tpu_custom_call``) and that the
 leaf streams in its own layout: in place, with no relayout copy of the leaf
-(the temp buffer stays far below the leaf's size).
+(the temp buffer stays far below the leaf's size).  The loss head's
+training step compiles with no float32 copy of the vocabulary-wide logits.
 
 The topology is described only inside the ``described_chip`` fixture
 (``tests/conftest.py``), never while a module is imported: one process at
 a time may load the TPU library.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,3 +100,58 @@ def test_leaf_shapes_are_granite_widths():
     from repro.models import MnistCNN
 
     assert jax.eval_shape(MnistCNN().init, jax.random.PRNGKey(0))["w3"].shape == (320, 50)
+
+
+def _old_head_loss(model):
+    """``CausalLM.loss`` as it was: log-softmax and ``take_along_axis`` over
+    a float32 copy of the logits."""
+    from repro import spans
+
+    def loss(params, batch):
+        logits, aux = model.forward(params, batch)
+        with jax.named_scope(spans.LM_HEAD):
+            logp = jax.nn.log_softmax(logits[..., :model.cfg.vocab_size], axis=-1)
+            nll = -jnp.take_along_axis(logp, batch["labels"][..., None], axis=-1)[..., 0]
+        return nll.mean() + model.cfg.router_aux_coef * aux
+    return loss
+
+
+def _vocab_f32_buffers(text, vocab):
+    """Top-level instructions of the entry computation with a float32 result
+    whose last dimension is the vocabulary."""
+    entry = text[text.index("\nENTRY"):]
+    return [line for line in entry.splitlines()
+            if re.search(rf"f32\[[\d,]*\b{vocab}\]", line.split(" metadata=")[0].split("(%")[0])]
+
+
+def _lm_head_scatters(text):
+    return [line for line in text.splitlines()
+            if "scatter" in line and "sdfeel.lm_head" in line]
+
+
+def test_loss_head_keeps_no_float32_logits(described_chip):
+    """The vmapped ``value_and_grad`` of ``CausalLM.loss`` (4 clients, S 1024,
+    vocabulary 49152, bf16) holds no float32 vocabulary-wide buffer and no
+    scatter in the loss head, and needs less temp than the log-softmax form
+    it replaced, which shows both."""
+    from repro.models import CausalLM
+
+    cfg = dataclasses.replace(GRANITE.reduced(), num_layers=1, vocab_size=GRANITE.vocab_size,
+                              dtype="bfloat16", remat=True, attn_chunk=512)
+    model = CausalLM(cfg)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((C,) + a.shape, a.dtype, sharding=described_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {k: jax.ShapeDtypeStruct((C, 1, 1024), jnp.int32, sharding=described_chip)
+             for k in ("tokens", "labels")}
+
+    def compile_step(loss):
+        return jax.jit(jax.vmap(jax.value_and_grad(loss))).lower(params, batch).compile()
+
+    new, old = compile_step(model.loss), compile_step(_old_head_loss(model))
+    v = cfg.padded_vocab
+    assert _vocab_f32_buffers(old.as_text(), v) and _lm_head_scatters(old.as_text())
+    assert not _vocab_f32_buffers(new.as_text(), v)
+    assert not _lm_head_scatters(new.as_text())
+    assert (new.memory_analysis().temp_size_in_bytes
+            < old.memory_analysis().temp_size_in_bytes)
