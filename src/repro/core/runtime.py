@@ -51,25 +51,21 @@ program with its big operands donated (params/opt_state updated in place),
 batches are pre-staged on device by ``pipeline.BatchPipeline`` /
 ``pipeline.gather_client_batches`` while the previous step computes, and
 per-step metrics stay on device until a logging or eval boundary, so the
-host never serializes the dispatch pipeline (``benchmarks/throughput.py``
-tracks the resulting protocol-iterations/sec).
+host never serializes the dispatch pipeline.
 
-Every scheduler also understands the *participation* axis (scenario key
-``"participation"``, see ``repro.participation``): a ``ParticipationPlan``
-produces per-round masks + renormalized intra-cluster weights that enter
-each compiled step as a traced array — who participates changes values, not
-programs.  ``"full"`` (or no plan) routes through the legacy static-weight
-path and is bit-identical to a plan-free run; sampled-out clients'
-updates are dropped (weight exactly 0), and the async scheduler skips a
-cluster event outright when none of its members participate.
+Who takes part in a round, with what weights, over what mixing and at what
+Section V-B price is one rule, held by ``rounds.FleetRounds``: each
+scheduler binds its ``FleetSpec`` (device profile, participation plan,
+fault schedule, client-state store) there once and asks it per round for a
+``RoundOperands`` record.  The record's traced operands (weights, mixing)
+enter the compiled step as arrays, so who participates changes values,
+never programs; a fleet with no sampling and no faults runs the static
+program, whose weights are the backend's bound ``m^``.
 
 New regimes (e.g. the semi-async deadline sampling of arXiv:2104.12678)
 plug in via ``register_scheduler`` and become available to the config-driven
 scenario factory ``make_run`` without touching the runtime — and, because
 aggregation goes through the backend layer, they inherit every fast path.
-
-The legacy entry points (``SDFEELSimulator``, ``AsyncSDFEEL``) have been
-removed; importing them raises ``ImportError`` pointing here.
 """
 from __future__ import annotations
 
@@ -87,6 +83,7 @@ from .backends import collective_supported, resolve_backend
 from .config import FleetSpec, RunConfig
 from .latency import LatencyModel
 from .protocol import SDFEELConfig
+from .rounds import FleetRounds
 from .staleness import staleness_mixing_matrix
 from .topology import TOPOLOGIES, Topology, mixing_matrix
 
@@ -190,39 +187,6 @@ def _contract_clients(params: PyTree, coef) -> PyTree:
     )
 
 
-def _event_time(
-    latency: Optional[LatencyModel], alpha: int, event: str, profile=None,
-    participants=None, clusters=None, t=None,
-) -> float:
-    """Per-iteration wall-clock of Section V-B for one sync protocol event.
-
-    With a ``DeviceProfile``, synchronous pacing is set by the slowest
-    effective client and the narrowest uplink (the straggler effect);
-    ``participants`` (a round's participation mask) restricts pacing to the
-    clients actually in the round — sampling's wall-clock upside.  With
-    ``clusters`` the event is priced along the per-cluster critical path
-    (each edge server waits for *its own* slowest member + narrowest uplink)
-    instead of the fleet-global envelope — see
-    ``FleetTiming.sync_event_time``.  ``t`` (the aggregation-round index)
-    prices a trace-scheduled fleet by that round's actual speeds and
-    availability instead of the trace's time average.
-    """
-    if profile is not None:
-        from ..hetero import FleetTiming
-
-        return FleetTiming(profile, latency).sync_event_time(
-            event, alpha, participants=participants, clusters=clusters, t=t
-        )
-    if latency is None:
-        return 0.0
-    t = latency.t_comp()
-    if event in ("intra", "inter"):
-        t += latency.t_comm_client_server()
-    if event == "inter":
-        t += alpha * latency.t_comm_server_server()
-    return t
-
-
 def _participant_batches(batch_source, k: int, res) -> PyTree:
     """Iteration ``k``'s batches for the resident slots only.
 
@@ -277,6 +241,32 @@ def _legacy_impl_backend(impl: str, clusters, p) -> str:
     return {"dense": "dense", "pallas": "pallas"}[impl]
 
 
+def _bind_backend(sched, impl: str, clusters, proto, state):
+    """Resolve ``sched``'s mesh and aggregation backend over ``clusters``.
+
+    Returns ``state`` (the resident client stack, or ``None`` offloaded)
+    sharded onto the backend's client mesh when it has one.  Under faults,
+    the collective backend checks the whole fault horizon host-side once:
+    traced mixing values cannot be checked on device.
+    """
+    from ..launch.mesh import resolve_client_mesh, shard_clients
+
+    spec = sched._backend_spec
+    if spec is None:
+        # honor impl="gossip" only where the collective path is valid
+        spec = _legacy_impl_backend(impl, clusters, proto.P())
+    sched.mesh = resolve_client_mesh(sched._mesh_spec, clusters.num_clients)
+    sched.backend = resolve_backend(
+        spec, clusters, proto.P(), proto.alpha, mesh=sched.mesh
+    )
+    if getattr(sched.backend, "mesh", None) is not None and state is not None:
+        state = shard_clients(state, sched.mesh, sched.backend.axis_name)
+    faults = sched.rounds.faults
+    if faults is not None and sched.backend.name == "collective":
+        faults.mixing_stack(0, faults.horizon() + 1, require_ring_stencil=True)
+    return state
+
+
 class SyncScheduler:
     """Algorithm 1 over stacked client models (host loop, CPU-friendly).
 
@@ -294,13 +284,10 @@ class SyncScheduler:
     host-synchronous seed behavior — only useful as a benchmark baseline).
 
     ``fleet`` (a ``repro.core.config.FleetSpec``) carries the who-axis as one
-    object: device ``profile``, ``participation`` plan spec, and the client
-    ``store`` (``repro.state``).  Participation samples who aggregates each
-    round (one round = ``tau1 * tau2`` iterations): the round's renormalized
-    weight vector enters the fused step as a traced operand, and — with a
-    ``DeviceProfile`` — the round's wall-clock is paced by its
-    *participants* only, along each cluster's own critical path.
-    ``None``/``"full"`` keeps the exact legacy code path.
+    object, bound in ``self.rounds`` (:class:`~repro.core.rounds.FleetRounds`):
+    each iteration takes its round's (one round = ``tau1 * tau2``
+    iterations) record of traced operands and is priced by the round's
+    pacing clients.
 
     With a ``host-offload`` store the scheduler runs on a fixed ``(k_max,
     ...)`` participant buffer: gathered at each round start, stepped through
@@ -322,56 +309,22 @@ class SyncScheduler:
             fleet, "SyncScheduler", profile=profile, participation=participation
         )
         self.profile = self.fleet.resolve_profile(cfg.clusters.num_clients)
+        self.rounds = FleetRounds(self.fleet, cfg, latency, self.profile)
         self.prefetch = prefetch
         self.params: PyTree = None
         self._backend_spec = backend
-        self.plan = None
-        self.store = None
-        self.faults = None
+        self.plan = self.store = self.faults = None
         self._pipeline = None
         self._pipeline_src = None
-        self._round_cache = None  # (round, weights jnp, effective mask np)
-        self._fault_cache = None  # (round, weights, mask, p, penalty, dts)
-        self._timing = None
-        if self.profile is not None:
-            from ..hetero import FleetTiming
-
-            self._timing = FleetTiming(self.profile, latency)
-        # §V-B per-event wall-clock depends only on construction args — price
-        # each event kind once instead of re-summing every step.  Fleets
-        # with a time-varying TraceSchedule are instead priced per round by
-        # that round's actual speeds (cached per round in _traced_event_time).
-        self._schedule = None if self.profile is None else self.profile.schedule
-        self._trace_cache = None  # (round, {event: dt})
-        self._event_times = {
-            e: _event_time(latency, cfg.alpha, e, self.profile,
-                           clusters=cfg.clusters)
-            for e in ("local", "intra", "inter")
-        }
 
     def bind(self, model, seed: int) -> None:
         cfg = self.cfg
         self.model = model
-        self.store = self.fleet.resolve_store(cfg.clusters.num_clients)
-        from ..participation import resolve_plan
-
-        self.plan = resolve_plan(
-            self.fleet.participation, cfg.clusters, profile=self.profile,
-            seed=seed,
-        )
-        # "full" routes through the legacy static-weight step: bit-identical
-        self._sampling = self.plan is not None and not self.plan.is_full
-        from ..faults import resolve_faults
-
-        # empty schedules resolve to None: zero fault events and faults=None
-        # take the identical (pre-fault, bitwise unchanged) code path below
-        self.faults = resolve_faults(self.fleet.faults, cfg.topology, cfg.clusters)
-        if self.faults is not None and not self.store.resident:
-            raise ValueError(
-                "fault injection requires a resident client-state store; "
-                "host-offload runs cannot thread per-round fault operands"
-            )
+        rounds = self.rounds
+        rounds.bind(seed, rows=cfg.clusters.num_clients)
+        self.plan, self.faults, self.store = rounds.plan, rounds.faults, rounds.store
         self._m = jnp.asarray(cfg.clusters.m(), jnp.float32)
+        self._buffer = self._buf_round = self._res = None
         if self.store.resident:
             self.params = stacked_init(model, cfg.clusters.num_clients, seed)
             self.store.attach(self)
@@ -380,28 +333,10 @@ class SyncScheduler:
             # fixed (k_max, ...) participant buffer; the aggregation runs
             # over the store's sub-fleet (same clusters, slot-sized)
             self.store.bind(cfg.clusters, model, seed)
-            self._buffer = None
-            self._buf_round = None
-            self._res = None
             agg_clusters = self.store.sub_clusters
-        spec = self._backend_spec
-        if spec is None:
-            spec = _legacy_impl_backend(cfg.aggregation_impl, agg_clusters, cfg.P())
-        from ..launch.mesh import resolve_client_mesh, shard_clients
-
-        self.mesh = resolve_client_mesh(self._mesh_spec, agg_clusters.num_clients)
-        self.backend = resolve_backend(
-            spec, agg_clusters, cfg.P(), cfg.alpha, mesh=self.mesh
+        self.params = _bind_backend(
+            self, cfg.aggregation_impl, agg_clusters, cfg, self.params
         )
-        if getattr(self.backend, "mesh", None) is not None and self.store.resident:
-            self.params = shard_clients(self.params, self.mesh,
-                                        self.backend.axis_name)
-        if self.faults is not None and self.backend.name == "collective":
-            # traced values can't be checked on device — validate the whole
-            # fault horizon host-side once (raises naming the bad round)
-            self.faults.mixing_stack(
-                0, self.faults.horizon() + 1, require_ring_stencil=True
-            )
         from .. import optim
         from .local_update import build_local_update
 
@@ -438,165 +373,45 @@ class SyncScheduler:
                 # into the same renormalized vector participation uses
                 return transition(local_sgd(params, batch), weights=weights, p=p)
 
-            if self.faults is not None:
-                return jax.jit(fused_faulted, donate_argnums=0)
-            return jax.jit(fused_sampled if self._sampling else fused,
-                           donate_argnums=0)
+            fn = {"static": fused, "weights": fused_sampled,
+                  "mixing": fused_faulted}[rounds.variant]
+            return jax.jit(fn, donate_argnums=0)
 
         self._step_fns = {e: make_step(e) for e in ("local", "intra", "inter")}
 
-    # -- participation plumbing ----------------------------------------------
     def _round_of(self, k: int) -> int:
         return (k - 1) // (self.cfg.tau1 * self.cfg.tau2)
-
-    def _round_participation(self, k: int):
-        """(weights jnp, effective mask np, per-event dt dict) of iteration
-        ``k``'s round.
-
-        The effective mask backfills empty clusters to full membership, so
-        pacing charges exactly the clients whose models the fallback
-        aggregation uploads.  The dt dict is filled lazily per event kind
-        (at most three entries) and discarded at the round boundary, so the
-        masked pricing costs one ``FleetTiming`` reduction per event kind
-        per round, not per iteration.
-
-        Offloaded stores slice the round's weight vector onto the resident
-        slots (padding slots weigh exactly 0) and pace by the residents.
-        """
-        r = self._round_of(k)
-        if self._round_cache is None or self._round_cache[0] != r:
-            if self.store.resident:
-                weights = self.plan.weights(r)
-                mask = self.plan.effective_mask(r)
-            else:
-                from ..state import sub_weights
-
-                res = self._residency_for_round(r)
-                weights = sub_weights(self.plan.weights(r), res)
-                mask = res.participant_mask(self.cfg.clusters.num_clients)
-            self._round_cache = (r, jnp.asarray(weights, jnp.float32), mask, {})
-        return self._round_cache[1], self._round_cache[2], self._round_cache[3]
-
-    def _masked_event_time(self, event: str, mask, times: dict, r: int) -> float:
-        if self.profile is None:
-            return self._event_times[event]
-        if event not in times:
-            times[event] = _event_time(
-                self.latency, self.cfg.alpha, event, self.profile,
-                participants=mask, clusters=self.cfg.clusters,
-                t=r if self._schedule is not None else None,
-            )
-        return times[event]
-
-    def _traced_event_time(self, event: str, r: int) -> float:
-        """Round ``r``'s full-fleet pricing for trace-scheduled fleets.
-
-        Cached per round (at most three event kinds), so a trace adds one
-        ``FleetTiming`` reduction per event kind per round — the same
-        amortization the participation-masked path gets from its dt dict.
-        """
-        if self._trace_cache is None or self._trace_cache[0] != r:
-            self._trace_cache = (r, {})
-        times = self._trace_cache[1]
-        if event not in times:
-            times[event] = _event_time(
-                self.latency, self.cfg.alpha, event, self.profile,
-                clusters=self.cfg.clusters, t=r,
-            )
-        return times[event]
-
-    # -- fault plumbing ------------------------------------------------------
-    def _fault_round(self, r: int):
-        """(weights jnp, mask np, p jnp, uplink penalty, dt dict) of round
-        ``r`` under the fault schedule — one compilation per round.
-
-        The plan's mask (ones without sampling) is ANDed with the schedule's
-        surviving-client mask and renormalized, so a crashed client's weight
-        is exactly 0; a fully-crashed cluster falls back to its full ``m^``
-        column (the edge server cannot aggregate nothing — the transition
-        must stay column-stochastic).  ``p`` is the round's per-component
-        mixing matrix; the retry penalty prices the round's failed uplinks
-        once, at its inter event.
-        """
-        if self._fault_cache is None or self._fault_cache[0] != r:
-            from ..participation import renormalize_weights
-
-            clusters = self.cfg.clusters
-            base = (
-                self.plan.mask(r) if self._sampling
-                else np.ones(clusters.num_clients, dtype=bool)
-            )
-            mask = base & self.faults.client_mask(r)
-            weights = renormalize_weights(
-                clusters.m_hat(), clusters.assignments, mask
-            )
-            p = jnp.asarray(self.faults.mixing_at(r), jnp.float32)
-            penalty = (
-                0.0 if self._timing is None
-                else self._timing.uplink_retry_penalty(self.faults.uplink_failed(r))
-            )
-            self._fault_cache = (
-                r, jnp.asarray(weights, jnp.float32), mask, p, penalty, {}
-            )
-        return self._fault_cache[1:]
-
-    # -- residency (host-offload stores) -------------------------------------
-    def _residency_for_round(self, r: int):
-        """Deterministic in ``r`` — prefetch and execution must agree."""
-        if self._sampling:
-            return self.store.residency(self.plan.mask(r))
-        return self.store.residency()
 
     # -- one protocol iteration (local + scheduled aggregation) -------------
     def _apply(self, k: int, staged_batch) -> tuple[str, float]:
         event = self.cfg.event_at(k)
-        if not self.store.resident:
-            return self._apply_offload(k, event, staged_batch)
-        if self.faults is not None:
-            r = self._round_of(k)
-            weights, mask, p, penalty, times = self._fault_round(r)
-            self.params = self._step_fns[event](
-                self.params, staged_batch, weights, p
-            )
-            dt = self._masked_event_time(event, mask, times, r)
-            if event == "inter":
-                dt += penalty
-            return event, dt
-        if self._sampling:
-            weights, mask, times = self._round_participation(k)
-            self.params = self._step_fns[event](self.params, staged_batch, weights)
-            dt = self._masked_event_time(event, mask, times, self._round_of(k))
-        else:
-            self.params = self._step_fns[event](self.params, staged_batch)
-            dt = (self._traced_event_time(event, self._round_of(k))
-                  if self._schedule is not None else self._event_times[event])
-        return event, dt
-
-    def _apply_offload(self, k: int, event: str, staged_batch) -> tuple[str, float]:
         r = self._round_of(k)
-        if self._buffer is None or self._buf_round != r:
-            self._res = self._residency_for_round(r)
+        resident = self.store.resident
+        if not resident and (self._buffer is None or self._buf_round != r):
+            self._res = self.rounds.residency(r)
             self._buffer = self.store.gather(self._res)
             self._buf_round = r
-        if self._sampling:
-            weights, mask, times = self._round_participation(k)
-            self._buffer = self._step_fns[event](self._buffer, staged_batch, weights)
-            dt = self._masked_event_time(event, mask, times, r)
+        ops = self.rounds.operands(r, self._res)
+        state = self._step_fns[event](
+            self.params if resident else self._buffer, staged_batch, *ops.args
+        )
+        dt = self.rounds.event_time(event, ops)
+        if resident:
+            self.params = state
         else:
-            self._buffer = self._step_fns[event](self._buffer, staged_batch)
-            dt = (self._traced_event_time(event, r)
-                  if self._schedule is not None else self._event_times[event])
+            self._buffer = state
         if event == "inter":
-            # round boundary: every resident's state is its cluster's
-            # post-gossip aggregate — fully representable by the store
-            self.store.scatter(self._res, self._buffer)
-            self._buffer = None
+            dt += ops.penalty
+            if not resident:
+                # round boundary: every resident's state is its cluster's
+                # post-gossip aggregate — fully representable by the store
+                self.store.scatter(self._res, self._buffer)
+                self._buffer = None
         return event, dt
 
     def advance(self, k: int, stacked_batch: dict) -> str:
         if not self.store.resident:
-            r = self._round_of(k)
-            res = self._residency_for_round(r)
+            res = self.rounds.residency(self._round_of(k))
             stacked_batch = jax.tree.map(
                 lambda x: np.asarray(x)[res.clients], stacked_batch
             )
@@ -604,7 +419,7 @@ class SyncScheduler:
 
     def iteration_time(self, event: str) -> float:
         """Full-fleet §V-B pacing (participation-masked rounds may be cheaper)."""
-        return self._event_times[event]
+        return self.rounds.event_time(event)
 
     def _next_batch(self, k: int, batch_source) -> PyTree:
         from .pipeline import BatchPipeline, device_batch
@@ -613,7 +428,7 @@ class SyncScheduler:
             producer = batch_source
         else:
             def producer(i: int) -> PyTree:
-                res = self._residency_for_round(self._round_of(i))
+                res = self.rounds.residency(self._round_of(i))
                 return _participant_batches(batch_source, i, res)
 
         if not self.prefetch:
@@ -673,8 +488,9 @@ class RoundScheduler:
     blocks on metrics between supersteps (materialize with ``float``/
     ``np.asarray`` at logging boundaries).
 
-    ``fleet`` (a ``FleetSpec``) carries profile/participation/store as one
-    object (the old ``profile=``/``participation=`` keywords warn).  With a
+    ``fleet`` (a ``FleetSpec``) carries profile/participation/faults/store
+    as one object, bound in ``self.rounds`` (a ``FleetRounds``; the old
+    ``profile=``/``participation=`` keywords warn).  With a
     ``host-offload`` store the superstep engine is compiled over the fixed
     ``(k_max, ...)`` slot buffer: one participation draw per superstep picks
     the residents, their batches and stageable host rows prefetch together,
@@ -699,31 +515,17 @@ class RoundScheduler:
             fleet, "RoundScheduler", profile=profile, participation=participation
         )
         self.profile = self.fleet.resolve_profile(fl.num_clients)
+        self._proto = fl.protocol()
+        self.rounds = FleetRounds(self.fleet, self._proto, latency, self.profile)
         self.rounds_per_step = rounds_per_step
         self.prefetch = prefetch
         self.params: PyTree = None
         self.opt_state: PyTree = None
         self._backend_spec = backend
-        self.plan = None
-        self.store = None
-        self.faults = None
+        self.plan = self.store = self.faults = None
         self._pipeline = None
         self._pipeline_src = None
         self._res_cache = None  # (step k, Residency) — prefetch must agree
-        self._proto = fl.protocol()
-        self._timing = None
-        if self.profile is not None:
-            from ..hetero import FleetTiming
-
-            self._timing = FleetTiming(self.profile, latency)
-        # §V-B wall-clock of one full round, priced once per event schedule;
-        # trace-scheduled fleets reprice per round in _round_time_at instead
-        self._schedule = None if self.profile is None else self.profile.schedule
-        self._round_time = sum(
-            _event_time(latency, fl.alpha, self._proto.event_at(i), self.profile,
-                        clusters=self._proto.clusters)
-            for i in range(1, self.iterations_per_round + 1)
-        )
 
     @property
     def iterations_per_round(self) -> int:
@@ -750,125 +552,46 @@ class RoundScheduler:
         fl = self.fl
         opt = self.optimizer or optim.sgd(fl.learning_rate)
         self.optimizer = opt
-        self.store = self.fleet.resolve_store(fl.num_clients)
-        from ..participation import resolve_plan
-
-        self.plan = resolve_plan(
-            self.fleet.participation, self._proto.clusters,
-            profile=self.profile, seed=seed,
-        )
-        self._sampling = self.plan is not None and not self.plan.is_full
-        from ..faults import resolve_faults
-
-        self.faults = resolve_faults(
-            self.fleet.faults, self._proto.topology, self._proto.clusters
-        )
-        if self.faults is not None and not self.store.resident:
-            raise ValueError(
-                "fault injection requires a resident client-state store; "
-                "host-offload runs cannot thread per-round fault operands"
-            )
+        rounds = self.rounds
+        # offloaded slots always carry weights: padding slots weigh exactly 0
+        rounds.bind(seed, rows=fl.num_clients, weighted_offload=True)
+        self.plan, self.faults, self.store = rounds.plan, rounds.faults, rounds.store
         if self.store.resident:
             self.params = stacked_init(model, fl.num_clients, seed)
             self.opt_state = opt.init(self.params)
             self.store.attach(self)
             engine_fl = fl
             agg_clusters = self._proto.clusters
+            state = (self.params, self.opt_state)
         else:
-            # superstep engine compiled over the store's (k_max, ...) slots;
-            # the per-slot weights mask pads to exactly 0, so the engine
-            # always runs its participation variant
+            # superstep engine compiled over the store's (k_max, ...) slots
             self.store.bind(self._proto.clusters, model, seed)
             engine_fl = dataclasses.replace(fl, num_clients=self.store.k_max)
             agg_clusters = self.store.sub_clusters
-            self._full_w = self._proto.clusters.m_hat()
-        spec = self._backend_spec
-        if spec is None:
-            # the compiled round engine historically always used dense;
-            # honor impl="gossip" only where the collective path is valid
-            spec = _legacy_impl_backend(fl.impl, agg_clusters, self._proto.P())
-        from ..launch.mesh import resolve_client_mesh, shard_clients
-
-        self.mesh = resolve_client_mesh(self._mesh_spec, agg_clusters.num_clients)
-        self.backend = resolve_backend(
-            spec, agg_clusters, self._proto.P(), fl.alpha, mesh=self.mesh
-        )
-        if getattr(self.backend, "mesh", None) is not None and self.store.resident:
-            self.params, self.opt_state = shard_clients(
-                (self.params, self.opt_state), self.mesh, self.backend.axis_name)
-        if self.faults is not None and self.backend.name == "collective":
-            # traced values can't be checked on device — validate the whole
-            # fault horizon host-side once (raises naming the bad round)
-            self.faults.mixing_stack(
-                0, self.faults.horizon() + 1, require_ring_stencil=True
-            )
+            state = None
+        state = _bind_backend(self, fl.impl, agg_clusters, self._proto, state)
+        if state is not None:
+            self.params, self.opt_state = state
         self._round_step = jax.jit(
             build_fl_round_step(model, opt, engine_fl, backend=self.backend,
                                 rounds_per_step=self.rounds_per_step,
-                                participation=(self._sampling
-                                               or not self.store.resident
-                                               or self.faults is not None),
-                                mixing=self.faults is not None),
+                                participation=rounds.variant != "static",
+                                mixing=rounds.variant == "mixing"),
             donate_argnums=(0, 1),
         )
 
     def round_time(self) -> float:
-        """Section V-B wall-clock of one full round (priced once at init)."""
-        return self._round_time
-
-    def _masked_round_time(self, r: int) -> float:
-        """§V-B wall-clock of round ``r`` paced by the clients that actually
-        enter its aggregation (empty clusters backfill to full membership).
-
-        Each event kind is priced once per round and summed by schedule —
-        three ``FleetTiming`` reductions, not ``tau1 * tau2``.
-        """
-        if self.profile is None:
-            return self._round_time
-        mask = self.plan.effective_mask(r)
-        return self._mask_round_time(
-            mask, t=r if self._schedule is not None else None
-        )
-
-    def _mask_round_time(self, mask, t: Optional[int] = None) -> float:
-        """Sum one round's schedule priced by ``mask``'s members — three
-        ``FleetTiming`` reductions, not ``tau1 * tau2``.  ``t`` prices a
-        trace-scheduled fleet by round ``t``'s actual speeds."""
-        times = {
-            e: _event_time(self.latency, self.fl.alpha, e, self.profile,
-                           participants=mask, clusters=self._proto.clusters, t=t)
-            for e in ("local", "intra", "inter")
-        }
-        return sum(
-            times[self._proto.event_at(i)]
-            for i in range(1, self.iterations_per_round + 1)
-        )
-
-    def _round_time_at(self, r: int) -> float:
-        """Full-fleet wall-clock of round ``r`` under a time-varying trace."""
-        times = {
-            e: _event_time(self.latency, self.fl.alpha, e, self.profile,
-                           clusters=self._proto.clusters, t=r)
-            for e in ("local", "intra", "inter")
-        }
-        return sum(
-            times[self._proto.event_at(i)]
-            for i in range(1, self.iterations_per_round + 1)
-        )
+        """Section V-B wall-clock of one full round of the full fleet."""
+        return self.rounds.round_time()
 
     # -- residency (host-offload stores) -------------------------------------
     def _residency_for_step(self, k: int):
         """Superstep ``k``'s slot assignment — one participation draw per
         superstep (round ``(k-1)*R``'s mask covers all ``R`` scanned rounds),
         deterministic in ``k`` so prefetch and execution agree."""
-        if self._res_cache is not None and self._res_cache[0] == k:
-            return self._res_cache[1]
-        if self._sampling:
-            res = self.store.residency(self.plan.mask((k - 1) * self.rounds_per_step))
-        else:
-            res = self.store.residency()
-        self._res_cache = (k, res)
-        return res
+        if self._res_cache is None or self._res_cache[0] != k:
+            self._res_cache = (k, self.rounds.residency((k - 1) * self.rounds_per_step))
+        return self._res_cache[1]
 
     def _superstep_batches(self, k: int, batch_source):
         from .pipeline import BatchPipeline, device_batch, stack_window
@@ -907,136 +630,39 @@ class RoundScheduler:
             self._pipeline_src = batch_source
         return self._pipeline.get(k)
 
-    def _offload_step(self, k: int, batch_source) -> StepEvent:
-        from ..state import sub_weights
-
-        with jax.profiler.TraceAnnotation(spans.STAGE):
-            stacked, staged = self._superstep_batches(k, batch_source)
-            res = self._residency_for_step(k)
-            buf = self.store.gather(res, staged)
-        # sgd's state is () so per-superstep re-init is free; stateful
-        # optimizers reset between supersteps under offload (documented)
-        opt_buf = self.optimizer.init(buf)
-        r0 = (k - 1) * self.rounds_per_step
-        w_full = self.plan.weights(r0) if self._sampling else self._full_w
-        weights = jnp.asarray(
-            np.tile(sub_weights(w_full, res), (self.rounds_per_step, 1)),
-            jnp.float32,
-        )
-        with jax.profiler.TraceAnnotation(spans.DISPATCH):
-            buf, _, losses = self._round_step(buf, opt_buf, stacked, weights)
-        self.store.scatter(res, buf)
-        if self.profile is None:
-            dt = self.rounds_per_step * self._round_time
-        else:
-            mask = res.participant_mask(self.fl.num_clients)
-            if self._schedule is not None:
-                dt = sum(self._mask_round_time(mask, t=r0 + i)
-                         for i in range(self.rounds_per_step))
-            else:
-                dt = self.rounds_per_step * self._mask_round_time(mask)
-        return StepEvent(
-            kind="round",
-            iteration=k * self.iterations_per_step,
-            dt=dt,
-            losses=losses,
-        )
-
-    # -- fault plumbing ------------------------------------------------------
-    def _fault_operands(self, r0: int):
-        """Stacked ``(R, C)`` weights, per-round masks and the ``(R, D, D)``
-        mixing stack for the superstep starting at round ``r0``.
-
-        Per round: the plan's mask (ones without sampling) ANDed with the
-        schedule's surviving clients, renormalized — crashed clients weigh
-        exactly 0, fully-crashed clusters fall back to their full ``m^``
-        column.  Both stacks are traced operands of one compiled superstep,
-        so the fault trace never recompiles.
-        """
-        from ..participation import renormalize_weights
-
-        clusters = self._proto.clusters
-        c = clusters.num_clients
-        weights, masks = [], []
-        for i in range(self.rounds_per_step):
-            r = r0 + i
-            base = (
-                self.plan.mask(r) if self._sampling
-                else np.ones(c, dtype=bool)
-            )
-            mask = base & self.faults.client_mask(r)
-            weights.append(
-                renormalize_weights(clusters.m_hat(), clusters.assignments, mask)
-            )
-            masks.append(mask)
-        mixing = self.faults.mixing_stack(r0, self.rounds_per_step)
-        return np.stack(weights), masks, mixing
-
-    def _fault_step(self, k: int, stacked) -> StepEvent:
-        r0 = (k - 1) * self.rounds_per_step
-        w_np, masks, mixing = self._fault_operands(r0)
-        with jax.profiler.TraceAnnotation(spans.DISPATCH):
-            self.params, self.opt_state, losses = self._round_step(
-                self.params, self.opt_state, stacked,
-                jnp.asarray(w_np, jnp.float32), jnp.asarray(mixing, jnp.float32),
-            )
-        if self.profile is None:
-            dt = self.rounds_per_step * self._round_time
-        else:
-            dt = sum(
-                self._mask_round_time(
-                    masks[i], t=(r0 + i) if self._schedule is not None else None
-                )
-                for i in range(self.rounds_per_step)
-            )
-        if self._timing is not None:
-            dt += sum(
-                self._timing.uplink_retry_penalty(self.faults.uplink_failed(r0 + i))
-                for i in range(self.rounds_per_step)
-            )
-        return StepEvent(
-            kind="round",
-            iteration=k * self.iterations_per_step,
-            dt=dt,
-            losses=losses,
-        )
-
     def step(self, k: int, batch_source) -> StepEvent:
-        if not self.store.resident:
-            return self._offload_step(k, batch_source)
+        resident = self.store.resident
         with jax.profiler.TraceAnnotation(spans.STAGE):
             stacked = self._superstep_batches(k, batch_source)
-        if self.faults is not None:
-            return self._fault_step(k, stacked)
-        if self._sampling:
-            # rounds (k-1)*R .. k*R-1, one weight vector per scanned round —
-            # a traced (R, C) operand, so redraws never recompile
-            r0 = (k - 1) * self.rounds_per_step
-            weights = jnp.asarray(
-                self.plan.stacked_weights(r0, self.rounds_per_step),
-                jnp.float32,
-            )
-            with jax.profiler.TraceAnnotation(spans.DISPATCH):
-                self.params, self.opt_state, losses = self._round_step(
-                    self.params, self.opt_state, stacked, weights
-                )
-            dt = sum(self._masked_round_time(r0 + i)
-                     for i in range(self.rounds_per_step))
+            res = None
+            if not resident:
+                stacked, staged = stacked
+                res = self._residency_for_step(k)
+                buf = self.store.gather(res, staged)
+        if resident:
+            state, opt_state = self.params, self.opt_state
         else:
-            with jax.profiler.TraceAnnotation(spans.DISPATCH):
-                self.params, self.opt_state, losses = self._round_step(
-                    self.params, self.opt_state, stacked
-                )
-            if self._schedule is not None:
-                r0 = (k - 1) * self.rounds_per_step
-                dt = sum(self._round_time_at(r0 + i)
-                         for i in range(self.rounds_per_step))
-            else:
-                dt = self.rounds_per_step * self._round_time
+            # sgd's state is () so per-superstep re-init is free; stateful
+            # optimizers reset between supersteps under offload (documented)
+            state, opt_state = buf, self.optimizer.init(buf)
+        # rounds (k-1)*R .. k*R-1, one record per scanned round; their
+        # operands stack to traced (R, ...) arrays, so redraws never recompile
+        r0 = (k - 1) * self.rounds_per_step
+        records = [self.rounds.operands(r0 + i, res, draw=r0)
+                   for i in range(self.rounds_per_step)]
+        args = tuple(jnp.stack(col) for col in zip(*(ops.args for ops in records)))
+        with jax.profiler.TraceAnnotation(spans.DISPATCH):
+            state, opt_state, losses = self._round_step(
+                state, opt_state, stacked, *args
+            )
+        if resident:
+            self.params, self.opt_state = state, opt_state
+        else:
+            self.store.scatter(res, state)
         return StepEvent(
             kind="round",
             iteration=k * self.iterations_per_step,
-            dt=dt,
+            dt=self.rounds.superstep_time(records),
             losses=losses,
         )
 
@@ -1102,9 +728,8 @@ class AsyncScheduler:
         self.fleet = _fleet_from_legacy(
             fleet, "AsyncScheduler", participation=participation
         )
-        self.plan = None
-        self.store = None
-        self.faults = None
+        self.rounds = FleetRounds(self.fleet, cfg, cfg.alpha_latency, cfg.profile)
+        self.plan = self.store = self.faults = None
         self._prefetched = None
 
     def bind(self, model, seed: int) -> None:
@@ -1114,13 +739,10 @@ class AsyncScheduler:
         self.model = model
         self.theta = cfg.theta()
         self.iter_times = cfg.iter_times()
+        timing = self.rounds.timing
         self._dropout = None
-        if cfg.profile is not None and np.any(cfg.profile.availability < 1.0):
-            from ..hetero import FleetTiming
-
-            self._dropout = FleetTiming(cfg.profile, cfg.alpha_latency).dropout_process(
-                cfg.clusters, seed=seed
-            )
+        if timing is not None and np.any(cfg.profile.availability < 1.0):
+            self._dropout = timing.dropout_process(cfg.clusters, seed=seed)
         d = cfg.clusters.num_clusters
         # per-cluster models, stacked (D, ...).  The async device state is
         # already cluster-sized, so a host-offload store wraps the y-stack as
@@ -1167,23 +789,10 @@ class AsyncScheduler:
             jnp.asarray(cfg.clusters.m_hat()[cfg.clusters.clients_of(j)], jnp.float32)
             for j in range(d)
         ]
-        from ..participation import resolve_plan
-
-        self.plan = resolve_plan(
-            self.fleet.participation, cfg.clusters, profile=cfg.profile,
-            seed=seed,
-        )
-        self._sampling = self.plan is not None and not self.plan.is_full
-        from ..faults import resolve_faults
-
         # the async fault axis is indexed by the global iteration count t —
         # the same granularity the eq. 21-22 gaps are measured in
-        self.faults = resolve_faults(self.fleet.faults, cfg.topology, cfg.clusters)
-        self._timing = None
-        if cfg.profile is not None:
-            from ..hetero import FleetTiming
-
-            self._timing = FleetTiming(cfg.profile, cfg.alpha_latency)
+        self.rounds.bind(seed)
+        self.plan, self.faults = self.rounds.plan, self.rounds.faults
         self._client_idx = [
             np.asarray(cfg.clusters.clients_of(j)) for j in range(d)
         ]
@@ -1255,12 +864,7 @@ class AsyncScheduler:
         iteration count ``t``).
         """
         idx = self._client_idx[d]
-        mask = (
-            self.plan.mask(k - 1)[idx] if self._sampling
-            else np.ones(len(idx), dtype=bool)
-        )
-        if self.faults is not None:
-            mask = mask & self.faults.client_mask(self.t)[idx]
+        mask = self.rounds.client_mask(k - 1, fault_r=self.t)[idx]
         if not mask.any():
             return None, False
         w = np.where(mask, self._m_hat_np[idx], 0.0)
@@ -1293,7 +897,7 @@ class AsyncScheduler:
         )
         m_hat, participated = (
             self._event_weights(k, d)
-            if (self._sampling or self.faults is not None)
+            if (self.rounds.sampling or self.faults is not None)
             else (self._m_hats[d], True)
         )
         if outage:
@@ -1329,11 +933,12 @@ class AsyncScheduler:
         service = self.iter_times[d]
         if self._dropout is not None:
             service *= self._dropout.attempts(d)
-        if self.faults is not None and self._timing is not None and not outage:
+        timing = self.rounds.timing
+        if self.faults is not None and timing is not None and not outage:
             idx = self._client_idx[d]
             failed = np.zeros(cfg.clusters.num_clients, dtype=bool)
             failed[idx] = self.faults.uplink_failed(r_fault)[idx]
-            service += self._timing.uplink_retry_penalty(failed)
+            service += timing.uplink_retry_penalty(failed)
         heapq.heappush(self._queue, (self.clock + service, d))
         if self.prefetch:
             # the queue top IS the next event — gather its batches now, while

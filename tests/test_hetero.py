@@ -399,9 +399,14 @@ def test_round_scheduler_prices_trace_per_round():
     sched = RoundScheduler(fl, latency=MNIST_LATENCY, profile=prof,
                            rounds_per_step=2)
     sched.bind(MnistCNN(), seed=0)
-    r0, r1 = sched._round_time_at(0), sched._round_time_at(1)
+    rounds = sched.rounds
+
+    def round_time_at(r):
+        return rounds.round_time(rounds.operands(r))
+
+    r0, r1 = round_time_at(0), round_time_at(1)
     assert r1 > r0
-    assert sched._round_time_at(2) == pytest.approx(r0)   # cycles
+    assert round_time_at(2) == pytest.approx(r0)   # cycles
     # the static average lies strictly between the two rows
     assert r0 < sched.round_time() < r1
     # a 2-round superstep is billed row by row, not 2x either row
